@@ -861,4 +861,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.util import use_compile_cache
+
+    use_compile_cache()
     sys.exit(main())

@@ -29,7 +29,7 @@ import threading
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 #: Mesh axes that carry pure data parallelism, outermost first. ``"batch"``
 #: resolves to whichever of these the current mesh actually has.
@@ -119,4 +119,9 @@ def shard(x: jax.Array, *entries) -> jax.Array:
     if mesh is None:
         return x
     spec = resolve_pspec(tuple(entries), mesh, x.shape)
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) != auto:
+        # with_sharding_constraint refers only to Auto axes, and
+        # jax.make_mesh builds Explicit ones by default
+        mesh = mesh.update(axis_types=auto)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

@@ -70,7 +70,6 @@ def _clock(fn, reps: int) -> float:
 
 
 def run_chained_bench(*, apps=CHAINED_APPS, reps: int = 5, seed: int = 0,
-                      interpret: bool = True,
                       max_macs: Optional[int] = None
                       ) -> tuple[list[dict], dict]:
     """Chained-vs-per-step pairs: ``(cases, per-app meta)``.
@@ -98,11 +97,10 @@ def run_chained_bench(*, apps=CHAINED_APPS, reps: int = 5, seed: int = 0,
             meta[app] = {"skipped": "no measured steps under budget"}
             continue
         inputs = synth_inputs(sched, seed=seed)
-        per_us = _clock(
-            lambda: run_schedule(sched, inputs, interpret=interpret), reps)
-        exe = compile_schedule(sched, inputs, interpret=interpret)
+        per_us = _clock(lambda: run_schedule(sched, inputs), reps)
+        exe = compile_schedule(sched, inputs)
         chained_us = _clock(exe.run, reps)
-        per = run_schedule(sched, inputs, interpret=interpret)
+        per = run_schedule(sched, inputs)
         got = exe.run()
         for op, y in got.items():
             assert np.array_equal(y, per[op]), \
@@ -122,13 +120,14 @@ def run_chained_bench(*, apps=CHAINED_APPS, reps: int = 5, seed: int = 0,
 
 
 def run_pallas_bench(*, quick: bool = False, reps: Optional[int] = None,
-                     seed: int = 0, interpret: bool = True,
+                     seed: int = 0,
                      shapes=None, widths=None, chained: bool = False,
                      chained_apps=None) -> dict:
     """Time every case; returns the BENCH_pallas.json payload dict."""
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
+    from repro.kernels import platform
     from repro.kernels import tiling as tl
     from repro.util import rand_words
 
@@ -145,20 +144,17 @@ def run_pallas_bench(*, quick: bool = False, reps: Optional[int] = None,
                         ).astype(jnp.int8)
         for bits in widths:
             w = jnp.asarray(rand_words(rng, bits, (k, n)))
-            wp = w.astype(kops.bp_weight_dtype(bits))
-            wu = w.astype(jnp.uint32)
+            limbs = kops.bp_limbs(w, bits)
 
-            def bs_unfused(wu=wu, x=x, bits=bits):
-                planes = kops.pack_weights(wu, bits, interpret=interpret)
-                return kops.matmul_bs(x, planes, interpret=interpret)
+            def bs_unfused(w=w, x=x, bits=bits):
+                return kops.matmul_bs(x, kops.pack_weights(w, bits))
 
             paths = (
                 ("bp", tl.bp_tiling(m, k, n),
-                 lambda x=x, wp=wp: kops.matmul_bp(
-                     x, wp, interpret=interpret)),
+                 lambda x=x, limbs=limbs: kops.matmul_bp(x, limbs)),
                 ("bs_fused", tl.fused_tiling(m, k, n),
                  lambda x=x, w=w, bits=bits: kops.matmul_bs_fused(
-                     x, w, bits, interpret=interpret)),
+                     x, w, bits)),
                 ("bs_unfused", tl.bs_tiling(m, k, n), bs_unfused),
             )
             for path, tiling, fn in paths:
@@ -168,12 +164,12 @@ def run_pallas_bench(*, quick: bool = False, reps: Optional[int] = None,
                     "padded": list(tiling.padded_dims),
                     "us": _clock(fn, reps),
                 })
-    payload = {"reps": reps, "quick": quick, "interpret": interpret,
+    payload = {"reps": reps, "quick": quick,
+               "interpret": platform.interpret(),
                "seed": seed, "cases": cases}
     if chained:
         ch_cases, ch_meta = run_chained_bench(
-            apps=chained_apps or CHAINED_APPS, reps=reps, seed=seed,
-            interpret=interpret)
+            apps=chained_apps or CHAINED_APPS, reps=reps, seed=seed)
         cases.extend(ch_cases)
         payload["chained"] = ch_meta
     return payload

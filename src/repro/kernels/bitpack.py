@@ -11,51 +11,62 @@ next multiple (zero rows pack to zero bits, so downstream bit-serial
 contractions are unaffected) and :func:`bitunpack` strips the padding on
 the way back (round-trip pinned in tests/test_kernels.py).
 
-Grid: (bits, K/32/bg, N/bn): each program packs `bg` groups of 32 rows for
-one bit position.
+Grid: (bits, Kg/bg, N/bn): each program packs ``bg`` groups of 32 rows
+for one bit position.  Blocks obey the TPU tiling rule: ``bg`` is a
+multiple of 8 groups or the whole Kg, ``bn`` a multiple of 128 lanes or
+the whole N.  The 32 rows of a group combine by a signed int32 sum of
+distinct powers of two (Mosaic reduces no unsigned integers), which is
+the packed word's bit pattern; it is bitcast to uint32 outside the kernel.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import platform
 
-def _kernel(w_ref, o_ref, *, bg: int):
+
+def _kernel(w_ref, o_ref):
     b = pl.program_id(0)
-    w = w_ref[...].astype(jnp.uint32)  # [bg*32, bn]
-    bit = (w >> b) & jnp.uint32(1)
-    grouped = bit.reshape(bg, 32, w.shape[-1])
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-    o_ref[0] = jnp.sum(grouped * weights[None, :, None], axis=1,
-                       dtype=jnp.uint32)
+    w = w_ref[...]  # [bg, 32, bn] int32 words
+    row = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+    bit = jax.lax.shift_right_logical(w, b) & 1
+    o_ref[0] = jnp.sum(bit << row, axis=1)
 
 
-def bitpack(w: jax.Array, bits: int, *, block_groups: int = 4,
-            block_n: int = 256, interpret: bool = True) -> jax.Array:
-    """w: unsigned words [K, N] (values < 2^bits) -> uint32
-    [bits, ceil(K/32), N]; K is zero-padded to the next multiple of 32."""
+def _block(dim: int, quantum: int, want: int) -> int:
+    """Largest multiple of ``quantum`` <= ``want`` dividing ``dim``, or
+    the whole ``dim`` when none does (the tiling rule's two options)."""
+    for blk in range(want - want % quantum, 0, -quantum):
+        if dim % blk == 0:
+            return blk
+    return dim
+
+
+def bitpack(w: jax.Array, bits: int, *, block_groups: int = 8,
+            block_n: int = 256) -> jax.Array:
+    """w: unsigned words [K, N] (values < 2^bits, any integer dtype) ->
+    uint32 [bits, ceil(K/32), N]; K is zero-padded to the next multiple
+    of 32."""
     K, N = w.shape
     pad = -K % 32
     if pad:
         w = jnp.pad(w, ((0, pad), (0, 0)))
     Kg = (K + pad) // 32
-    bg = min(block_groups, Kg)
-    while Kg % bg:
-        bg -= 1
-    bn = min(block_n, N)
-    while N % bn:
-        bn //= 2
-    return pl.pallas_call(
-        functools.partial(_kernel, bg=bg),
+    # int32 bit patterns: the uint32 -> int32 convert wraps losslessly
+    words = w.astype(jnp.int32).reshape(Kg, 32, N)
+    bg = _block(Kg, 8, block_groups)
+    bn = _block(N, 128, block_n)
+    packed = pl.pallas_call(
+        _kernel,
         grid=(bits, Kg // bg, N // bn),
-        in_specs=[pl.BlockSpec((bg * 32, bn), lambda b, g, n: (g, n))],
+        in_specs=[pl.BlockSpec((bg, 32, bn), lambda b, g, n: (g, 0, n))],
         out_specs=pl.BlockSpec((1, bg, bn), lambda b, g, n: (b, g, n)),
-        out_shape=jax.ShapeDtypeStruct((bits, Kg, N), jnp.uint32),
-        interpret=interpret,
-    )(w)
+        out_shape=jax.ShapeDtypeStruct((bits, Kg, N), jnp.int32),
+        interpret=platform.interpret(),
+    )(words)
+    return jax.lax.bitcast_convert_type(packed, jnp.uint32)
 
 
 def bitunpack(planes: jax.Array, k: int | None = None) -> jax.Array:

@@ -9,7 +9,8 @@ and y = x @ W is computed plane-by-plane:
 
 Each plane's product is a binary-matrix contraction: the kernel unpacks the
 plane tile in VMEM (shift+mask -- the "sense amplifier read" of the slice)
-and feeds the MXU with a 0/1 operand.  Low-precision weights cost
+and feeds the MXU with a 0/1 int8 operand against int8 activations (the
+MXU multiplies integers only as int8 x int8 -> int32).  Low-precision weights cost
 proportionally fewer plane passes -- exactly the BS latency scaling
 (Table 2: N-bit -> N cycles), while dense full-width BP costs one pass.
 
@@ -31,25 +32,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import platform
 from repro.kernels.tiling import bs_tiling
 
 
 def _kernel(x_ref, planes_ref, o_ref, acc_ref, *, bits: int, bk: int,
             k_steps: int):
-    # x_ref: [bm, bk] int ; planes_ref: [bits, bk//32, bn] uint32
+    # x_ref: [bm, bk] int8 ; planes_ref: [bits, bk//32, bn] uint32
     # o_ref / acc_ref: [bm, bn] int32
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)  # MXU operand
+    x = x_ref[...]  # int8 MXU operand
     shifts = jnp.arange(32, dtype=jnp.uint32)
     acc = acc_ref[...]
     for b in range(bits):  # bit-serial plane loop
         packed = planes_ref[b]  # [bk//32, bn] uint32
         bits_kn = ((packed[:, None, :] >> shifts[None, :, None])
                    & jnp.uint32(1))  # [bk//32, 32, bn]
-        plane = bits_kn.reshape(bk, -1).astype(jnp.int32)
+        plane = bits_kn.reshape(bk, -1).astype(jnp.int8)
         acc = acc + (jax.lax.dot(x, plane,
                                  preferred_element_type=jnp.int32) << b)
     acc_ref[...] = acc
@@ -61,9 +63,10 @@ def _kernel(x_ref, planes_ref, o_ref, acc_ref, *, bits: int, bk: int,
 
 def bitserial_matmul(x: jax.Array, planes: jax.Array, *,
                      block_m: int = 128, block_n: int = 128,
-                     block_k: int = 512,
-                     interpret: bool = True) -> jax.Array:
-    """x: int [M, K]; planes: uint32 [bits, K//32, N] -> int32 [M, N]."""
+                     block_k: int = 512) -> jax.Array:
+    """x: int8 [M, K]; planes: uint32 [bits, K//32, N] -> int32 [M, N]."""
+    if x.dtype != jnp.int8:
+        raise TypeError(f"MXU activations must be int8, got {x.dtype}")
     M, K = x.shape
     bits, Kg, N = planes.shape
     # bitpack zero-pads ragged K into whole 32-row groups; those zero plane
@@ -91,6 +94,6 @@ def bitserial_matmul(x: jax.Array, planes: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((t.pm, t.pn), jnp.int32),
         # VMEM accumulator persisted across the sequential K axis
         scratch_shapes=[pltpu.VMEM((t.bm, t.bn), jnp.int32)],
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(x, planes)
     return out[:M, :N] if (t.pm, t.pn) != (M, N) else out

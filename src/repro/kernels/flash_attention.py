@@ -1,4 +1,4 @@
-"""Flash attention Pallas kernel (TPU target, validated in interpret mode).
+"""Flash attention Pallas kernel (compiled on a TPU, interpreted on the CPU).
 
 The pure-JAX streaming attention in models/layers.py materializes the
 per-chunk score/probability tensors at HLO boundaries -- the dominant memory
@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import platform
 
 NEG_INF = -1e30
 
@@ -61,8 +63,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    block_k: int = 128) -> jax.Array:
     """q: [B, Sq, H, D]; k, v: [B, Sk, H, D] (MHA; GQA callers repeat KV).
     Returns [B, Sq, H, D]."""
     B, Sq, H, D = q.shape
@@ -92,6 +93,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

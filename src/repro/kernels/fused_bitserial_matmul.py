@@ -13,8 +13,10 @@ intermediate tensor ever round-trips to HBM.
 The layout story is unchanged -- the weight matrix is still *consumed*
 bit-serially, ``bits`` MXU plane passes, so latency scales with precision
 exactly as the unfused kernel (Table 2) -- only the pack pass stops being
-a separately timed, separately stored artifact.  Weights must be
-unsigned ``bits``-wide values (any int dtype holding them); results are
+a separately timed, separately stored artifact.  Activations must be
+int8 and each 0/1 plane is cast to int8: the TPU MXU multiplies integers
+only as int8 x int8 -> int32.  Weights must be unsigned ``bits``-wide
+values (any int dtype holding them); results are
 bit-exact with ``bitpack`` -> ``bitserial_matmul`` and with
 ``ref.bitserial_matmul_ref`` (int32 wraparound semantics, see
 ``bitparallel_matmul``).
@@ -28,20 +30,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import platform
 from repro.kernels.tiling import fused_tiling
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, k_steps: int):
-    # x_ref: [bm, bk] int ; w_ref: [bk, bn] unsigned words (int storage)
+    # x_ref: [bm, bk] int8 ; w_ref: [bk, bn] unsigned words (int storage)
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
+    x = x_ref[...]
     w = w_ref[...].astype(jnp.uint32)
     acc = acc_ref[...]
     for b in range(bits):  # in-register bitpack: slice plane b of the tile
-        plane = ((w >> b) & jnp.uint32(1)).astype(jnp.int32)
+        plane = ((w >> b) & jnp.uint32(1)).astype(jnp.int8)
         acc = acc + (jax.lax.dot(x, plane,
                                  preferred_element_type=jnp.int32) << b)
     acc_ref[...] = acc
@@ -53,14 +56,17 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, bits: int, k_steps: int):
 
 def fused_bitserial_matmul(x: jax.Array, w: jax.Array, bits: int, *,
                            block_m: int = 128, block_n: int = 128,
-                           block_k: int = 128,
-                           interpret: bool = True) -> jax.Array:
-    """x: int [M, K]; w: unsigned ``bits``-wide words [K, N] -> int32 [M, N]."""
+                           block_k: int = 128) -> jax.Array:
+    """x: int8 [M, K]; w: unsigned ``bits``-wide words [K, N] -> int32
+    [M, N]."""
     if not 1 <= bits <= 32:
         raise ValueError(f"bits must be in [1, 32], got {bits}")
+    if x.dtype != jnp.int8:
+        raise TypeError(f"MXU activations must be int8, got {x.dtype}")
     M, K = x.shape
     K2, N = w.shape
-    assert K == K2, (K, K2)
+    if K != K2:
+        raise ValueError(f"contraction mismatch: x K={K}, w K={K2}")
     t = fused_tiling(M, K, N, block_m=block_m, block_n=block_n,
                      block_k=block_k)
     if (t.pm, t.pk) != (M, K):
@@ -79,6 +85,6 @@ def fused_bitserial_matmul(x: jax.Array, w: jax.Array, bits: int, *,
         out_shape=jax.ShapeDtypeStruct((t.pm, t.pn), jnp.int32),
         # VMEM accumulator persisted across the sequential K axis
         scratch_shapes=[pltpu.VMEM((t.bm, t.bn), jnp.int32)],
-        interpret=interpret,
+        interpret=platform.interpret(),
     )(x, w)
     return out[:M, :N] if (t.pm, t.pn) != (M, N) else out

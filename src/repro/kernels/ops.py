@@ -12,21 +12,10 @@ import jax.numpy as jnp
 from repro.core.cost_model import Layout
 from repro.core.taxonomy import Recommendation, classify
 from repro.kernels.bitpack import bitpack, bitunpack
-from repro.kernels.bitparallel_matmul import bitparallel_matmul
+from repro.kernels.bitparallel_matmul import bitparallel_matmul, split_limbs
 from repro.kernels.bitserial_matmul import bitserial_matmul
 from repro.kernels.fused_bitserial_matmul import fused_bitserial_matmul
 from repro.workloads.ir import Op
-
-
-def bp_weight_dtype(weight_bits: int):
-    """Smallest signed dtype that holds unsigned ``weight_bits`` words
-    losslessly for the BP (word) kernel.  The pre-PR-9 path cast every
-    weight to int8, silently wrapping widths >= 8."""
-    if weight_bits <= 7:
-        return jnp.int8
-    if weight_bits <= 15:
-        return jnp.int16
-    return jnp.int32
 
 
 def thread_activations(y: jax.Array, m: int, k: int) -> jax.Array:
@@ -49,10 +38,16 @@ def thread_activations(y: jax.Array, m: int, k: int) -> jax.Array:
     return flat[:need].reshape(m, k).astype(jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def pack_weights(w: jax.Array, bits: int, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bits",))
+def pack_weights(w: jax.Array, bits: int):
     """BP -> BS layout conversion (the transpose unit)."""
-    return bitpack(w, bits, interpret=interpret)
+    return bitpack(w, bits)
+
+
+@functools.partial(jax.jit, static_argnames=("bits",))
+def bp_limbs(w: jax.Array, bits: int):
+    """Word-form weights -> the int8 limb stack the BP kernel reads."""
+    return split_limbs(w, bits)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -62,37 +57,37 @@ def unpack_weights(planes: jax.Array, k: int | None = None):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "interpret", "block_m", "block_n", "block_k"))
-def matmul_bs(x: jax.Array, planes: jax.Array, interpret: bool = True,
+    "block_m", "block_n", "block_k"))
+def matmul_bs(x: jax.Array, planes: jax.Array,
               block_m: int = 128, block_n: int = 128, block_k: int = 512):
     # bitpack zero-pads K to a multiple of 32; mirror the padding on the
     # activation side (zero rows contribute nothing to the contraction)
     k_planes = planes.shape[1] * 32
     if x.shape[1] != k_planes:
         x = jnp.pad(x, ((0, 0), (0, k_planes - x.shape[1])))
-    return bitserial_matmul(x, planes, interpret=interpret,
+    return bitserial_matmul(x, planes,
                             block_m=block_m, block_n=block_n,
                             block_k=max(block_k, 256))
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "interpret", "block_m", "block_n", "block_k"))
-def matmul_bp(x: jax.Array, w: jax.Array, interpret: bool = True,
+    "block_m", "block_n", "block_k"))
+def matmul_bp(x: jax.Array, limbs: jax.Array,
               block_m: int = 128, block_n: int = 128, block_k: int = 128):
-    return bitparallel_matmul(x, w, interpret=interpret, block_m=block_m,
+    """BP word matmul over resident int8 limbs (:func:`bp_limbs`)."""
+    return bitparallel_matmul(x, limbs, block_m=block_m,
                               block_n=block_n, block_k=block_k)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "bits", "interpret", "block_m", "block_n", "block_k"))
+    "bits", "block_m", "block_n", "block_k"))
 def matmul_bs_fused(x: jax.Array, w: jax.Array, bits: int,
-                    interpret: bool = True, block_m: int = 128,
+                    block_m: int = 128,
                     block_n: int = 128, block_k: int = 128):
     """One-kernel BS path: packs plane slices in VMEM and accumulates the
     plane loop without materializing the ``[bits, K/32, N]`` artifact.
     Bit-exact with ``pack_weights`` -> ``matmul_bs``."""
-    return fused_bitserial_matmul(x, w, bits, interpret=interpret,
-                                  block_m=block_m, block_n=block_n,
+    return fused_bitserial_matmul(x, w, bits, block_m=block_m, block_n=block_n,
                                   block_k=block_k)
 
 
@@ -118,7 +113,7 @@ def choose_layout(*, weight_bits: int, m: int, n: int, k: int,
 
 def planned_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int,
                    plan=None, op_name: str | None = None,
-                   fuse_pack: bool = False, interpret: bool = True):
+                   fuse_pack: bool = False):
     """Dispatch x @ w to the BS (bitplane) or BP (word) kernel per a
     compiled :class:`repro.plan.ir.LayoutPlan` -- the same plan the cost
     model priced.  ``plan.layout_for(op_name)`` picks the kernel; with no
@@ -135,18 +130,12 @@ def planned_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int,
         layout = Layout.BS if rec == Recommendation.BS else Layout.BP
     if layout is Layout.BS:
         if fuse_pack:
-            return (matmul_bs_fused(x, w, weight_bits, interpret=interpret),
-                    Layout.BS)
-        planes = pack_weights(w.astype(jnp.uint32), weight_bits,
-                              interpret=interpret)
-        return matmul_bs(x, planes, interpret=interpret), Layout.BS
-    return (matmul_bp(x, w.astype(bp_weight_dtype(weight_bits)),
-                      interpret=interpret), Layout.BP)
+            return matmul_bs_fused(x, w, weight_bits), Layout.BS
+        return matmul_bs(x, pack_weights(w, weight_bits)), Layout.BS
+    return matmul_bp(x, bp_limbs(w, weight_bits)), Layout.BP
 
 
-def layout_aware_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int,
-                        interpret: bool = True):
+def layout_aware_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int):
     """Advisor-driven dispatch (no plan): x @ w via the BS or BP kernel
     per the Table-8 verdict. w: unsigned ints < 2^weight_bits, [K, N]."""
-    return planned_matmul(x, w, weight_bits=weight_bits,
-                          interpret=interpret)
+    return planned_matmul(x, w, weight_bits=weight_bits)

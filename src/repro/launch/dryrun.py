@@ -39,15 +39,6 @@ from repro.train.step import (  # noqa: E402
 )
 
 
-def _cost_dict(compiled) -> dict:
-    """`Compiled.cost_analysis()` returns a list of per-program dicts on
-    jax<=0.4.x CPU backends and a bare dict on newer ones -- normalize."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _abstract_moments(structure):
     ab = abstract_params(structure)
     mom = jax.tree.map(
@@ -99,7 +90,7 @@ def _count_once(cfg_k, shape, mesh):
     try:
         jitted, args = build_cell(cfg_k, shape, mesh)
         compiled = jitted.lower(*args).compile()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         txt = compiled.as_text()
         stats = hlo_analysis.collect_collectives(txt, default_group=16)
         from repro import util as _util
@@ -211,7 +202,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
 
             mem = compiled.memory_analysis()
             print(mem)  # proves it fits
-            cost = _cost_dict(compiled)
+            cost = compiled.cost_analysis()
             print({k: cost.get(k) for k in ("flops", "bytes accessed")})
             hlo = compiled.as_text()
     except Exception as e:  # noqa: BLE001

@@ -15,14 +15,20 @@ TP/SP.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, elastic re-configurations)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (tests, elastic re-configurations).
+
+    Every axis is Auto: ``dist.sharding.shard`` places activations with
+    ``with_sharding_constraint``, which only accepts Auto axes, and
+    ``jax.make_mesh`` defaults to Explicit ones."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -62,7 +62,10 @@ def execute_schedule(schedule: MachineSchedule, workload: Workload, *,
     ``functional=False`` keeps the static program-cycle accounting but
     skips the jax array simulation (identical numbers, no jax work).
     ``mesh`` shards the leading array axis of every batched run; the
-    array count is padded up to a device multiple when needed.
+    array count is padded up to a device multiple when needed.  The
+    simulated cells start from seeded random bits, and ``states`` holds
+    each program's final :class:`pim.executor.ExecState` (device arrays,
+    in ``programs`` order).
     """
     from repro.pim import programs as pr
 
@@ -159,6 +162,7 @@ def execute_schedule(schedule: MachineSchedule, workload: Workload, *,
         "arrays_simulated": 0,
         "mesh_devices": 1,
         "programs": [],
+        "states": [],
         "io": None,
     }
     if functional and prog_arrays:
@@ -174,7 +178,7 @@ def execute_schedule(schedule: MachineSchedule, workload: Workload, *,
 def _run_programs(prog_arrays: dict, geometry: Geometry, mesh,
                   collect_hlo: bool) -> dict:
     import jax
-    import jax.numpy as jnp
+    import numpy as np
 
     from repro.pim.executor import make_runner, run_batched
 
@@ -187,7 +191,11 @@ def _run_programs(prog_arrays: dict, geometry: Geometry, mesh,
         sharding = NamedSharding(mesh, P(mesh.axis_names[0], None, None))
 
     programs = []
+    states = []
     arrays_simulated = 0
+    # host-drawn bits: the same cells whatever the mesh, so a sharded run
+    # is comparable bit for bit with a single-device one
+    rng = np.random.default_rng(0)
     biggest = None
     for prog, arrays in sorted(prog_arrays.items(),
                                key=lambda kv: kv[0].key):
@@ -200,11 +208,11 @@ def _run_programs(prog_arrays: dict, geometry: Geometry, mesh,
         cols = geometry.cols
         if prog.layout is Layout.BP and cols % prog.width:
             cols += prog.width - cols % prog.width
-        cells = jnp.zeros((n_arrays, prog.rows, cols), bool)
-        if sharding is not None:
-            cells = jax.device_put(cells, sharding)
+        cells = jax.device_put(
+            rng.random((n_arrays, prog.rows, cols)) < 0.5, sharding)
         state = run_batched(prog, cells)
-        jax.block_until_ready(state.cells)
+        jax.block_until_ready(state)
+        states.append(state)
         arrays_simulated = max(arrays_simulated, n_arrays)
         programs.append({
             "name": prog.name, "layout": prog.layout.value,
@@ -237,7 +245,8 @@ def _run_programs(prog_arrays: dict, geometry: Geometry, mesh,
             "hlo_boundary_bytes": hlo_total,
             "ratio": (hlo_total / model_total) if model_total else 0.0,
         }
-    return {"programs": programs, "arrays_simulated": arrays_simulated,
+    return {"programs": programs, "states": states,
+            "arrays_simulated": arrays_simulated,
             "mesh_devices": n_dev, "io": io}
 
 

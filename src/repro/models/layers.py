@@ -343,8 +343,7 @@ def lm_logits(p, x, embedding=None):
 
 
 def pim_quantized_linear(x, w, *, weight_bits: int, plan=None,
-                         op_name: str | None = None,
-                         interpret: bool = True):
+                         op_name: str | None = None):
     """Quantized linear dispatched by a compiled ``repro.plan`` layout
     plan -- the model layer consumes the same BP/BS decision the cost
     model priced (falling back to the Table-8 advisor when no plan is
@@ -359,7 +358,7 @@ def pim_quantized_linear(x, w, *, weight_bits: int, plan=None,
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
     y, layout = planned_matmul(x2, w, weight_bits=weight_bits, plan=plan,
-                               op_name=op_name, interpret=interpret)
+                               op_name=op_name)
     return y.reshape(lead + (w.shape[1],)), layout
 
 

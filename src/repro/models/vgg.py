@@ -32,7 +32,7 @@ VGG_BATCH = 128  # batch inference, as in the formula workload
 VGG_FCS = [(512, 512), (512, 512), (512, 10)]
 
 
-def abstract_inputs(which: str = "vgg16"):
+def abstract_inputs(which: str = "vgg16", batch: int = VGG_BATCH):
     """(params, images) ShapeDtypeStruct pytrees for :func:`forward`."""
     import jax
     import jax.numpy as jnp
@@ -47,7 +47,7 @@ def abstract_inputs(which: str = "vgg16"):
             c_in = c
     for fi, (k, n) in enumerate(VGG_FCS):
         params[f"fc{fi}"] = jax.ShapeDtypeStruct((k, n), f32)
-    images = jax.ShapeDtypeStruct((VGG_BATCH, 32, 32, 3), f32)  # NHWC
+    images = jax.ShapeDtypeStruct((batch, 32, 32, 3), f32)  # NHWC
     return params, images
 
 
@@ -76,13 +76,15 @@ def forward(params, images, which: str = "vgg16"):
     return x
 
 
-def traced_vgg(which: str = "vgg16"):
-    """Trace :func:`forward` into a ``traced/<which>`` Workload."""
+def traced_vgg(which: str = "vgg16", batch: int = VGG_BATCH):
+    """Trace :func:`forward` into a ``traced/<which>`` Workload (the
+    registry's at batch 128; a smaller ``batch`` cuts scale, never the
+    layer widths)."""
     from repro.workloads.trace import trace_workload
 
-    params, images = abstract_inputs(which)
+    params, images = abstract_inputs(which, batch)
     return trace_workload(
         lambda p, im: forward(p, im, which), params, images,
         name=f"traced/{which}", source="traced",
-        description=f"{which.upper()} batch-{VGG_BATCH} CIFAR-10 "
+        description=f"{which.upper()} batch-{batch} CIFAR-10 "
                     "inference, jaxpr-traced")

@@ -30,9 +30,10 @@ The lowering contract:
   bit-identical to the unfused pack->matmul path and to the pim
   micro-op executor's MAC decomposition of the same op.
 
-Ops whose *padded* MAC volume (times plane passes for BS) exceeds
-``max_macs`` are lowered as modelled-only too -- an honest
-"too large to time here" note, never a silently clamped measurement.
+Ops whose *padded* MAC volume (times MXU passes: planes for BS, limbs
+for BP -- :func:`mxu_passes`) exceeds ``max_macs`` are lowered as
+modelled-only too -- an honest "too large to time here" note, never a
+silently clamped measurement.
 """
 from __future__ import annotations
 
@@ -53,6 +54,14 @@ MAX_BS_WIDTH = 32
 #: default padded-MAC budget per kernel launch (interpret-mode throughput
 #: is ~10^8 MAC/s; 2^31 keeps a single launch under ~30 s)
 DEFAULT_MAX_MACS = 2 ** 31
+
+
+def mxu_passes(layout: Layout, width: int) -> int:
+    """int8 MXU passes one step takes: a plane per bit for BS, a 7-bit
+    limb per pass for BP (``kernels.bitparallel_matmul``)."""
+    from repro.kernels.bitparallel_matmul import n_limbs
+
+    return width if layout is Layout.BS else n_limbs(width)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,13 +195,13 @@ def lower_plan_pallas(plan: LayoutPlan, workload, *,
             continue
         fused = fuse_pack and layout is Layout.BS and repack == "bp2bs"
         t = _tiling(layout, fused, m, k, n)
-        planes = op.width if layout is Layout.BS else 1
-        if t.padded_macs * planes > max_macs:
+        passes = mxu_passes(layout, op.width)
+        if t.padded_macs * passes > max_macs:
             steps.append(PallasStep(
                 op=op.name, kind=op.kind, layout=layout, width=op.width,
                 kernel=None, repack=repack, dims=(m, k, n),
                 padded_dims=t.padded_dims,
-                note=f"over budget: {t.padded_macs * planes} padded MACs "
+                note=f"over budget: {t.padded_macs * passes} padded MACs "
                      f"> max_macs={max_macs} -- not timed"))
             continue
         if layout is Layout.BP:
@@ -244,7 +253,7 @@ def _thread_np(y: np.ndarray, m: int, k: int) -> np.ndarray:
 
 
 def run_schedule(schedule: PallasSchedule, inputs: dict, *,
-                 interpret: bool = True, thread: bool = True) -> dict:
+                 thread: bool = True) -> dict:
     """Execute every measured step from the host; return
     {op: int32 [m, n] result}.
 
@@ -276,14 +285,11 @@ def run_schedule(schedule: PallasSchedule, inputs: dict, *,
             x = jnp.asarray(x)
         w = jnp.asarray(w)
         if s.layout is Layout.BP:
-            y = kops.matmul_bp(x, w.astype(kops.bp_weight_dtype(s.width)),
-                               interpret=interpret)
+            y = kops.matmul_bp(x, kops.bp_limbs(w, s.width))
         elif s.kernel == "fused_bitserial_matmul":
-            y = kops.matmul_bs_fused(x, w, s.width, interpret=interpret)
+            y = kops.matmul_bs_fused(x, w, s.width)
         else:
-            planes = kops.pack_weights(w.astype(jnp.uint32), s.width,
-                                       interpret=interpret)
-            y = kops.matmul_bs(x, planes, interpret=interpret)
+            y = kops.matmul_bs(x, kops.pack_weights(w, s.width))
         results[s.op] = np.asarray(y)
     return results
 
@@ -307,7 +313,7 @@ def reference_results(schedule: PallasSchedule, inputs: dict, *,
 
 
 def time_schedule(schedule: PallasSchedule, inputs: dict, *,
-                  reps: int = 5, interpret: bool = True) -> list[dict]:
+                  reps: int = 5) -> list[dict]:
     """Median-of-``reps`` wall-clock per measured step (plus modelled rows).
 
     Returns one record per schedule step: ``{op, kind, layout, kernel,
@@ -348,21 +354,18 @@ def time_schedule(schedule: PallasSchedule, inputs: dict, *,
             w = jnp.asarray(w)
 
             if s.layout is Layout.BP:
-                wt = w.astype(kops.bp_weight_dtype(s.width))
+                limbs = kops.bp_limbs(w, s.width)
 
-                def fn(x=x, wt=wt):
-                    return kops.matmul_bp(x, wt, interpret=interpret)
+                def fn(x=x, limbs=limbs):
+                    return kops.matmul_bp(x, limbs)
             elif s.kernel == "fused_bitserial_matmul":
                 def fn(x=x, w=w, bits=s.width):
-                    return kops.matmul_bs_fused(x, w, bits,
-                                                interpret=interpret)
+                    return kops.matmul_bs_fused(x, w, bits)
             else:
                 # unfused: the pack pass is part of the measured path --
                 # that is exactly the artifact fusion removes
                 def fn(x=x, w=w, bits=s.width):
-                    planes = kops.pack_weights(w.astype(jnp.uint32), bits,
-                                               interpret=interpret)
-                    return kops.matmul_bs(x, planes, interpret=interpret)
+                    return kops.matmul_bs(x, kops.pack_weights(w, bits))
             jax.block_until_ready(fn())  # warmup: trace + compile
             ts = []
             for _ in range(reps):

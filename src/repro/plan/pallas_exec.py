@@ -10,12 +10,13 @@ that execution model (DESIGN.md Sec. 15):
 * :func:`compile_schedule` lowers an entire schedule -- every measured
   step plus its bp2bs/bs2bp repack -- into ONE jitted program.  Weights
   are converted/packed once at *compile* time into a device-resident
-  param pytree: BP steps hold words at ``bp_weight_dtype``, BS-resident
-  steps hold pre-packed ``[bits, K/32, N]`` planes.  Boundary repacks the
+  param pytree: BP steps hold int8 limb stacks ``[ceil(bits/7), K, N]``
+  (the form the BP kernel reads), BS-resident steps hold pre-packed
+  ``[bits, K/32, N]`` planes.  Boundary repacks the
   plan charges stay *in* the program: a ``bp2bs`` step keeps word-form
   params and packs in-flight (through the fused bitpack-matmul when the
   schedule fused it), a ``bs2bp`` step keeps plane-form params and
-  unpacks in-flight.
+  unpacks (and splits into limbs) in-flight.
 * Step results thread to successor activations along the Workload
   ``deps`` DAG (``kernels.ops.thread_activations``) -- real dataflow, so
   XLA cannot elide or reorder the chain, and synthetic operands exist
@@ -32,8 +33,8 @@ program and with the numpy ``reference_results`` (pinned by
 
 Executables are content-addressed (:class:`ExecutableCache`, the
 ``serve.plan_cache`` sha256 pattern) by canonical schedule dict + kernel
-source fingerprint + seed + interpret flag -- in-memory only, because an
-executable holds live jitted closures and device buffers.
+source fingerprint + seed + JAX backend platform -- in-memory only,
+because an executable holds live jitted closures and device buffers.
 """
 from __future__ import annotations
 
@@ -77,14 +78,16 @@ def kernel_fingerprint() -> str:
 
 
 def schedule_key(schedule: PallasSchedule, *, seed: int = 0,
-                 interpret: bool = True,
                  fingerprint: Optional[str] = None) -> str:
     """Content address of a compiled schedule: sha256 over the canonical
     schedule dict (steps, layouts, dims, repacks, deps, fuse_pack), the
-    synth seed, the interpret flag, and the kernel source fingerprint."""
+    synth seed, the JAX backend platform (which decides interpreted or
+    compiled kernels), and the kernel source fingerprint."""
+    import jax
+
     blob = json.dumps(
         {"schedule": schedule.to_dict(), "seed": seed,
-         "interpret": interpret,
+         "platform": jax.default_backend(),
          "fingerprint": fingerprint or kernel_fingerprint()},
         sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(blob.encode()).hexdigest()[:24]
@@ -153,7 +156,7 @@ class ScheduleExecutable:
 
 def compile_schedule(schedule: PallasSchedule,
                      inputs: Optional[dict] = None, *, seed: int = 0,
-                     interpret: bool = True, donate: bool = True,
+                     donate: bool = True,
                      key: Optional[str] = None) -> ScheduleExecutable:
     """Compile ``schedule`` into ONE jitted program (module doc).
 
@@ -168,7 +171,8 @@ def compile_schedule(schedule: PallasSchedule,
 
     from repro.kernels import ops as kops
     from repro.kernels.bitpack import bitpack, bitunpack
-    from repro.kernels.bitparallel_matmul import bitparallel_matmul
+    from repro.kernels.bitparallel_matmul import (bitparallel_matmul,
+                                                  split_limbs)
     from repro.kernels.bitserial_matmul import bitserial_matmul
     from repro.kernels.fused_bitserial_matmul import fused_bitserial_matmul
 
@@ -176,13 +180,9 @@ def compile_schedule(schedule: PallasSchedule,
     if inputs is None:
         inputs = synth_inputs(schedule, seed=seed)
     if key is None:
-        key = schedule_key(schedule, seed=seed, interpret=interpret)
+        key = schedule_key(schedule, seed=seed)
     producer = schedule.threaded_producers()
     steps = schedule.measured_steps
-
-    def _as_planes(w, width):
-        return kops.pack_weights(w.astype(jnp.uint32), width,
-                                 interpret=interpret)
 
     # ---- compile-time residency: convert/pack every weight once ------
     params: dict[str, Any] = {}
@@ -196,22 +196,22 @@ def compile_schedule(schedule: PallasSchedule,
             if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
                 # the operand arrives plane-resident; the plan-charged
                 # unpack is part of the program, not of compile
-                params[s.op] = _as_planes(w, s.width)
+                params[s.op] = kops.pack_weights(w, s.width)
             else:
-                params[s.op] = w.astype(kops.bp_weight_dtype(s.width))
+                params[s.op] = kops.bp_limbs(w, s.width)
         elif s.repack == "bp2bs":
             # word-resident: the plan-charged pack runs in-program
             # (folded into the fused kernel when the schedule fused it)
             params[s.op] = w
         else:
-            params[s.op] = _as_planes(w, s.width)
+            params[s.op] = kops.pack_weights(w, s.width)
 
     def _bs(x, planes):
         # mirror kops.matmul_bs: bitpack zero-pads K to a multiple of 32
         k_planes = planes.shape[1] * 32
         if x.shape[1] != k_planes:
             x = jnp.pad(x, ((0, 0), (0, k_planes - x.shape[1])))
-        return bitserial_matmul(x, planes, interpret=interpret)
+        return bitserial_matmul(x, planes)
 
     def program(xs, ps):
         out = {}
@@ -223,15 +223,12 @@ def compile_schedule(schedule: PallasSchedule,
             w = ps[s.op]
             if s.layout is Layout.BP:
                 if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
-                    w = bitunpack(w, k).astype(
-                        kops.bp_weight_dtype(s.width))
-                y = bitparallel_matmul(x, w, interpret=interpret)
+                    w = split_limbs(bitunpack(w, k), s.width)
+                y = bitparallel_matmul(x, w)
             elif s.kernel == "fused_bitserial_matmul":
-                y = fused_bitserial_matmul(x, w, s.width,
-                                           interpret=interpret)
+                y = fused_bitserial_matmul(x, w, s.width)
             elif s.repack == "bp2bs":
-                y = _bs(x, bitpack(w.astype(jnp.uint32), s.width,
-                                   interpret=interpret))
+                y = _bs(x, bitpack(w, s.width))
             else:
                 y = _bs(x, w)
             out[s.op] = y
@@ -287,10 +284,10 @@ class ExecutableCache:
 
     def get_or_compile(self, schedule: PallasSchedule,
                        inputs: Optional[dict] = None, *, seed: int = 0,
-                       interpret: bool = True, donate: bool = True
+                       donate: bool = True
                        ) -> tuple[ScheduleExecutable, str, bool]:
         """-> ``(executable, key, hit)``."""
-        key = schedule_key(schedule, seed=seed, interpret=interpret,
+        key = schedule_key(schedule, seed=seed,
                            fingerprint=self.fingerprint)
         exe = self._mem.get(key)
         if exe is not None:
@@ -299,7 +296,7 @@ class ExecutableCache:
             return exe, key, True
         self.misses += 1
         exe = compile_schedule(schedule, inputs, seed=seed,
-                               interpret=interpret, donate=donate, key=key)
+                               donate=donate, key=key)
         self._mem[key] = exe
         self.puts += 1
         while len(self._mem) > self.capacity:

@@ -104,12 +104,11 @@ class PhaseBatcher:
 
     def __init__(self, max_batch: int = 64,
                  execute_budget: int = DEFAULT_EXECUTE_BUDGET,
-                 executables=None, interpret: bool = True, seed: int = 0):
+                 executables=None, seed: int = 0):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1 (got {max_batch})")
         self.max_batch = max_batch
         self.execute_budget = execute_budget
-        self.interpret = interpret
         self.seed = seed
         self._executables = executables
 
@@ -152,15 +151,13 @@ class PhaseBatcher:
         returned row still come from the host integers (the simulated
         accounting is layout math, not wall-clock).
         """
-        from repro.core.cost_model import Layout
-        from repro.plan.pallas import lower_plan_pallas
+        from repro.plan.pallas import lower_plan_pallas, mxu_passes
 
         def measurable_macs(sched) -> int:
             total = 0
             for s in sched.measured_steps:
                 m_p, k_p, n_p = s.padded_dims
-                planes = s.width if s.layout is Layout.BS else 1
-                total += m_p * k_p * n_p * planes
+                total += m_p * k_p * n_p * mxu_passes(s.layout, s.width)
             return total
 
         rep, sched, best = None, None, (-1, -1)
@@ -171,7 +168,7 @@ class PhaseBatcher:
             if cand > best:
                 rep, sched, best = m, cand_sched, cand
         exe, key, hit = self.executables.get_or_compile(
-            sched, seed=self.seed, interpret=self.interpret)
+            sched, seed=self.seed)
         if warmup:  # steady-state: warm outside the timed window
             exe.run()
         t0 = time.perf_counter()

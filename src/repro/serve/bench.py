@@ -91,6 +91,9 @@ def run_serve_bench(n_requests: int = 2048, *, seed: int = 0,
             "execute_budget": execute_budget,
             "measured_steps": sum(r["measured_steps"] for r in rows),
             "modelled_steps": sum(r["modelled_steps"] for r in rows),
+            #: groups whose compiled program ran no kernel at all
+            "groups_all_modelled": sum(1 for r in rows
+                                       if not r["measured_steps"]),
         },
         "batches": {
             "count": len(groups),

@@ -17,6 +17,7 @@ from __future__ import annotations
 import hashlib
 import inspect
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +56,23 @@ def rand_words(rng: np.random.Generator, width: int, shape) -> np.ndarray:
         raw = rng.integers(0, 1 << 32, shape, dtype=np.uint64)
         return raw.astype(np.uint32).view(np.int32)
     return rng.integers(0, 1 << width, shape).astype(np.int32)
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, is JAX's own setting and
+    wins: nothing else is set.  Otherwise the cache lives at a fixed
+    path inside the checkout (``.jax-cache/``) -- the path is part of
+    what a cached entry is found by, so it never names a temporary
+    directory, a pid or a time.  Entry points call this (``python -m
+    repro``, ``chip_smoke.py``); tests do not.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[2] / ".jax-cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def set_remat(value: bool) -> None:

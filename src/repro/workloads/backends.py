@@ -447,11 +447,9 @@ class PallasBackend(_SequentialEstimateMany):
 
     name = "pallas"
 
-    def __init__(self, tile: int = 128, interpret: bool = True,
-                 reps: int = 5, max_macs: int = PALLAS_MAX_MACS,
-                 fused: bool = True):
+    def __init__(self, tile: int = 128, reps: int = 5,
+                 max_macs: int = PALLAS_MAX_MACS, fused: bool = True):
         self.tile = tile
-        self.interpret = interpret
         self.reps = reps
         self.max_macs = max_macs
         self.fused = fused
@@ -541,20 +539,16 @@ class PallasBackend(_SequentialEstimateMany):
                             ).astype(jnp.int8)
             w = jnp.asarray(rng.integers(0, 1 << min(bits, 31),
                                          (k, n)).astype(np.int32))
-            wp = w.astype(kops.bp_weight_dtype(bits))
-            bp_us = clock(lambda: kops.matmul_bp(
-                x, wp, interpret=self.interpret, **bk))
+            limbs = kops.bp_limbs(w, bits)
+            bp_us = clock(lambda: kops.matmul_bp(x, limbs, **bk))
             if self.fused:
                 bs_us = clock(lambda: kops.matmul_bs_fused(
-                    x, w, bits, interpret=self.interpret, **bk))
+                    x, w, bits, **bk))
                 bs_note = "fused"
             else:
                 # unfused: the pack pass is part of the measured BS path
                 def bs_fn():
-                    planes = kops.pack_weights(w.astype(jnp.uint32), bits,
-                                               interpret=self.interpret)
-                    return kops.matmul_bs(x, planes,
-                                          interpret=self.interpret)
+                    return kops.matmul_bs(x, kops.pack_weights(w, bits))
                 bs_us = clock(bs_fn)
                 bs_note = "unfused (pack on path)"
             rec = kops.choose_layout(weight_bits=bits, m=m, n=n, k=k)
@@ -573,8 +567,9 @@ class PallasBackend(_SequentialEstimateMany):
             summary={"bp_us": tot_bp, "bs_us": tot_bs,
                      "measured_ops": measured, "total_ops": len(workload.ops),
                      "coverage": measured / len(workload.ops)},
-            notes=("wall-clock of interpret-mode Pallas kernels over full "
-                   "op dims (correctness-path on CPU; see "
+            notes=(f"wall-clock of Pallas kernels on {jax.default_backend()}"
+                   " over full op dims (interpret mode on the CPU: a "
+                   "correctness path, not a speed; see "
                    "benchmarks/pallas_bench)",)
             if measured else ())
 

@@ -76,7 +76,7 @@ MOVEMENT_PRIMITIVES = frozenset({
 
 #: call-like primitives whose inner jaxpr is inlined 1:1
 _CALL_PRIMITIVES = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
+    "jit", "pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
     "checkpoint", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
 })
@@ -242,7 +242,7 @@ class _Tracer:
 
     # ----------------------------------------------------------- plumbing
     def read(self, atom) -> _VarInfo:
-        from jax.core import Literal
+        from jax.extend.core import Literal
 
         if isinstance(atom, Literal):
             return _EMPTY

@@ -250,3 +250,38 @@ def test_plan_pallas_flag_times_kernel_schedule(tmp_path):
     env = json.loads((tmp_path / "plans.json").read_text())
     pallas = env["payload"]["gemv"]["pallas"]
     assert pallas["steps"] and all(r["dims"] for r in pallas["steps"])
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """The chip smoke run never carries on without a TPU: non-zero exit
+    and no ``"ok": true`` result line."""
+    env = {"PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu",
+           "PATH": os.environ.get("PATH", "/usr/bin")}
+    root = Path(__file__).parent.parent
+    proc = subprocess.run([sys.executable, str(root / "chip_smoke.py")],
+                          capture_output=True, text=True, env=env,
+                          cwd=root, timeout=300)
+    assert proc.returncode != 0
+    lines = proc.stdout.strip().splitlines()
+    assert not lines or '"ok": true' not in lines[-1]
+    assert "no TPU" in proc.stderr
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir(tmp_path, from_env):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, and otherwise to the fixed in-checkout ``.jax-cache``."""
+    env = {"PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu",
+           "PATH": os.environ.get("PATH", "/usr/bin")}
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = ("import jax; from repro.util import use_compile_cache; "
+            "print(use_compile_cache()); "
+            "print(jax.config.jax_compilation_cache_dir)")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    returned, configured = proc.stdout.split()
+    want = (str(tmp_path) if from_env
+            else str(Path(__file__).resolve().parent.parent / ".jax-cache"))
+    assert returned == configured == want

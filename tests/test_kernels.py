@@ -2,6 +2,7 @@
 interpret mode."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.kernels import ref
@@ -258,13 +259,46 @@ def test_planned_matmul_fuse_pack_dispatch():
     np.testing.assert_array_equal(np.asarray(y_f), np.asarray(y_u))
 
 
-def test_bp_weight_dtype_is_lossless():
-    assert ops.bp_weight_dtype(1) == jnp.int8
-    assert ops.bp_weight_dtype(7) == jnp.int8
-    assert ops.bp_weight_dtype(8) == jnp.int16   # 255 doesn't fit int8
-    assert ops.bp_weight_dtype(15) == jnp.int16
-    assert ops.bp_weight_dtype(16) == jnp.int32
-    assert ops.bp_weight_dtype(32) == jnp.int32
+@pytest.mark.parametrize("bits", [8, 16, 32])
+def test_bp_limb_split_is_exact(bits):
+    """Words of 8 bits or more run as ceil(bits/7) int8 MXU passes; the
+    shifted limb sum is bit-exact mod 2^32 with the plain-integer oracle,
+    over the full uint32 range at width 32."""
+    from repro.kernels.bitparallel_matmul import n_limbs
+    from repro.util import rand_words
+
+    rng = np.random.default_rng(bits)
+    m, k, n = 40, 300, 130
+    x = jnp.asarray(rng.integers(-128, 128, (m, k), dtype=np.int8))
+    w = rand_words(rng, bits, (k, n))
+    if bits == 32:
+        assert (w < 0).any()           # the top bit is exercised
+    limbs = ops.bp_limbs(jnp.asarray(w), bits)
+    assert limbs.dtype == jnp.int8 and limbs.shape == (n_limbs(bits), k, n)
+    assert n_limbs(bits) == -(-bits // 7)
+    got = np.asarray(bitparallel_matmul(x, limbs))
+    want = np.asarray(ref.bitparallel_matmul_ref(x, jnp.asarray(w)))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_platform_helper_refuses_unknown_backend(monkeypatch):
+    import jax
+
+    from repro.kernels import platform
+
+    assert platform.interpret() is True      # the test process: CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        platform.interpret()
+
+
+def test_kernels_require_int8_mxu_operands():
+    x = jnp.zeros((8, 64), jnp.int32)
+    w = jnp.zeros((64, 16), jnp.int32)
+    with pytest.raises(TypeError, match="int8"):
+        bitparallel_matmul(x, w)
+    with pytest.raises(TypeError, match="int8"):
+        ops.matmul_bs_fused(x, w, 4)
 
 
 # --------------------------------------- pallas-bench regression gate ------
