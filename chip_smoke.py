@@ -173,6 +173,7 @@ def main_path_phase(name: str, workload) -> None:
     import jax
     import numpy as np
 
+    from repro import spans
     from repro.core.cost_model import Layout
     from repro.plan import (compile_plan, compile_schedule,
                             lower_plan_pallas, synth_inputs)
@@ -186,7 +187,11 @@ def main_path_phase(name: str, workload) -> None:
     inputs = synth_inputs(sched, seed=SEED)
     exe = compile_schedule(sched, inputs, seed=SEED)
     first, second = exe.run(), exe.run()
-    warm_us = exe.time(reps=3)
+    # the second call is warm: read its spans
+    warm_us = spans.last("schedule.run").dur_ns / 1e3
+    phases = " ".join(
+        f"{p}_us={spans.last(f'schedule.{p}').dur_ns / 1e3:.0f}"
+        for p in ("place", "dispatch", "wait", "fetch"))
     want = {op: np.asarray(y)
             for op, y in xla_reference(sched, inputs).items()}
     bad = [op for op in want
@@ -197,7 +202,8 @@ def main_path_phase(name: str, workload) -> None:
           f"n_modelled={exe.n_modelled} repacks={sched.n_repacks} "
           f"kernels={','.join(kernels)}", flush=True)
     print(f"  {name}: compile_us={exe.compile_us:.0f} "
-          f"warm_run_us={warm_us:.0f} params_bytes={exe.params_bytes} "
+          f"warm_run_us={warm_us:.0f} ({phases}) "
+          f"params_bytes={exe.params_bytes} "
           f"peak_bytes_in_use={peak} exact_steps="
           f"{len(want) - len(bad)}/{len(want)}", flush=True)
     check(not bad, f"{name}: steps differ from the XLA reference: {bad}")

@@ -65,6 +65,7 @@ def bitpack(w: jax.Array, bits: int, *, block_groups: int = 8,
         out_specs=pl.BlockSpec((1, bg, bn), lambda b, g, n: (b, g, n)),
         out_shape=jax.ShapeDtypeStruct((bits, Kg, N), jnp.int32),
         interpret=platform.interpret(),
+        name="bitpack",
     )(words)
     return jax.lax.bitcast_convert_type(packed, jnp.uint32)
 

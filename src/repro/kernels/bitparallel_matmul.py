@@ -108,5 +108,6 @@ def bitparallel_matmul(x: jax.Array, w: jax.Array, *,
         # VMEM accumulator persisted across the sequential K axis
         scratch_shapes=[pltpu.VMEM((t.bm, t.bn), jnp.int32)],
         interpret=platform.interpret(),
+        name="bitparallel_matmul",
     )(x, w)
     return out[:M, :N] if (t.pm, t.pn) != (M, N) else out

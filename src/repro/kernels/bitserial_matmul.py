@@ -95,5 +95,6 @@ def bitserial_matmul(x: jax.Array, planes: jax.Array, *,
         # VMEM accumulator persisted across the sequential K axis
         scratch_shapes=[pltpu.VMEM((t.bm, t.bn), jnp.int32)],
         interpret=platform.interpret(),
+        name="bitserial_matmul",
     )(x, planes)
     return out[:M, :N] if (t.pm, t.pn) != (M, N) else out

@@ -94,5 +94,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, D), jnp.float32),   # output accumulator
         ],
         interpret=platform.interpret(),
+        name="flash_attention",
     )(qr, kr, vr)
     return out.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)

@@ -86,5 +86,6 @@ def fused_bitserial_matmul(x: jax.Array, w: jax.Array, bits: int, *,
         # VMEM accumulator persisted across the sequential K axis
         scratch_shapes=[pltpu.VMEM((t.bm, t.bn), jnp.int32)],
         interpret=platform.interpret(),
+        name="fused_bitserial_matmul",
     )(x, w)
     return out[:M, :N] if (t.pm, t.pn) != (M, N) else out

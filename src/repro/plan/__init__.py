@@ -18,7 +18,8 @@ Public surface (see README.md in this directory and DESIGN.md Sec. 10)::
 
     from repro.plan import compile_schedule              # chained mode
     exe = compile_schedule(sched)   # ONE jitted program, weights resident
-    exe.run(); exe.time()           # warm steady-state wall-clock
+    exe.run()                       # warm: one call of the program
+    spans.last("schedule.run")      # its time (``repro.spans``)
 
 CLI: ``python -m repro plan <workload> [--geometry RxCxA] [--execute]
 [--pallas]``.

@@ -44,6 +44,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core.cost_model import Layout
 from repro.plan.ir import LayoutPlan
 
@@ -166,6 +167,7 @@ def _tiling(layout: Layout, fused: bool, m: int, k: int, n: int):
     return tl.fused_tiling(m, k, n) if fused else tl.bs_tiling(m, k, n)
 
 
+@spans.span("plan.lower")
 def lower_plan_pallas(plan: LayoutPlan, workload, *,
                       fuse_pack: bool = True,
                       max_macs: int = DEFAULT_MAX_MACS) -> PallasSchedule:

@@ -41,14 +41,13 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
-import statistics
-import time
 import warnings
 from collections import OrderedDict
 from typing import Any, Optional
 
 import numpy as np
 
+from repro import spans
 from repro.core.cost_model import Layout
 from repro.plan.pallas import MAX_BS_WIDTH, PallasSchedule, synth_inputs
 
@@ -98,9 +97,10 @@ class ScheduleExecutable:
     """A :class:`PallasSchedule` compiled to one jitted device program.
 
     ``compile_us`` charges everything the steady state never pays again:
-    operand synthesis, weight conversion/packing into device residency,
-    tracing, XLA compilation, and the first (warming) execution.
-    ``run()``/``time()`` are the warm path.
+    operand synthesis, weight conversion/packing into device residency
+    (span ``schedule.pack``), tracing, XLA compilation, and the first
+    (warming) execution (span ``schedule.first_run``).  ``run()`` is the
+    warm path.
     """
 
     schedule: PallasSchedule
@@ -123,25 +123,30 @@ class ScheduleExecutable:
 
         Entry activations are re-placed from host copies each call (the
         program donates its input buffers), so running twice is safe and
-        bit-identical -- the donation-regression contract.
+        bit-identical -- the donation-regression contract.  Each call
+        records the spans ``schedule.run`` > ``schedule.place`` /
+        ``.dispatch`` / ``.wait`` / ``.fetch`` and the counters
+        ``schedule.place_bytes`` / ``schedule.fetch_bytes``
+        (``repro.spans``).
         """
         import jax
         import jax.numpy as jnp
 
-        placed = {op: jnp.asarray(v) for op, v in self._entry.items()}
-        out = jax.block_until_ready(self._fn(placed, self._params))
-        self.runs += 1
-        return {op: np.asarray(y) for op, y in out.items()}
-
-    def time(self, reps: int = 5) -> float:
-        """Median warm wall-clock (us) of the whole chained program."""
-        self.run()  # warm (compile already ran once at build time)
-        samples = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            self.run()
-            samples.append((time.perf_counter() - t0) * 1e6)
-        return statistics.median(samples)
+        with spans.span("schedule.run", key=self.key, call=self.runs):
+            with spans.span("schedule.place"):
+                placed = {op: jnp.asarray(v) for op, v in self._entry.items()}
+            spans.count("schedule.place_bytes",
+                        sum(v.nbytes for v in self._entry.values()))
+            with spans.span("schedule.dispatch"):
+                out = self._fn(placed, self._params)
+            with spans.span("schedule.wait"):
+                jax.block_until_ready(out)
+            with spans.span("schedule.fetch"):
+                got = {op: np.asarray(y) for op, y in out.items()}
+            spans.count("schedule.fetch_bytes",
+                        sum(v.nbytes for v in got.values()))
+            self.runs += 1
+        return got
 
     def summary(self) -> dict:
         return {"key": self.key, "workload": self.schedule.workload,
@@ -176,35 +181,37 @@ def compile_schedule(schedule: PallasSchedule,
     from repro.kernels.bitserial_matmul import bitserial_matmul
     from repro.kernels.fused_bitserial_matmul import fused_bitserial_matmul
 
-    t0 = time.perf_counter()
-    if inputs is None:
-        inputs = synth_inputs(schedule, seed=seed)
-    if key is None:
-        key = schedule_key(schedule, seed=seed)
-    producer = schedule.threaded_producers()
-    steps = schedule.measured_steps
+    with spans.span("schedule.pack"):
+        if inputs is None:
+            inputs = synth_inputs(schedule, seed=seed)
+        if key is None:
+            key = schedule_key(schedule, seed=seed)
+        producer = schedule.threaded_producers()
+        steps = schedule.measured_steps
 
-    # ---- compile-time residency: convert/pack every weight once ------
-    params: dict[str, Any] = {}
-    entry: dict[str, np.ndarray] = {}
-    for s in steps:
-        x, w = inputs[s.op]
-        if s.op not in producer:
-            entry[s.op] = np.asarray(x)
-        w = jnp.asarray(w)
-        if s.layout is Layout.BP:
-            if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
-                # the operand arrives plane-resident; the plan-charged
-                # unpack is part of the program, not of compile
-                params[s.op] = kops.pack_weights(w, s.width)
+        # ---- compile-time residency: convert/pack every weight once ----
+        params: dict[str, Any] = {}
+        entry: dict[str, np.ndarray] = {}
+        for s in steps:
+            x, w = inputs[s.op]
+            if s.op not in producer:
+                entry[s.op] = np.asarray(x)
+            w = jnp.asarray(w)
+            if s.layout is Layout.BP:
+                if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
+                    # the operand arrives plane-resident; the plan-charged
+                    # unpack is part of the program, not of compile
+                    params[s.op] = kops.pack_weights(w, s.width)
+                else:
+                    params[s.op] = kops.bp_limbs(w, s.width)
+            elif s.repack == "bp2bs":
+                # word-resident: the plan-charged pack runs in-program
+                # (folded into the fused kernel when the schedule fused it)
+                params[s.op] = w
             else:
-                params[s.op] = kops.bp_limbs(w, s.width)
-        elif s.repack == "bp2bs":
-            # word-resident: the plan-charged pack runs in-program
-            # (folded into the fused kernel when the schedule fused it)
-            params[s.op] = w
-        else:
-            params[s.op] = kops.pack_weights(w, s.width)
+                params[s.op] = kops.pack_weights(w, s.width)
+        # the span covers the conversions, not only their dispatch
+        jax.block_until_ready(params)
 
     def _bs(x, planes):
         # mirror kops.matmul_bs: bitpack zero-pads K to a multiple of 32
@@ -218,34 +225,38 @@ def compile_schedule(schedule: PallasSchedule,
         for s in steps:
             m, k, _n = s.dims
             src = producer.get(s.op)
-            x = (kops.thread_activations(out[src], m, k)
-                 if src is not None else xs[s.op])
-            w = ps[s.op]
-            if s.layout is Layout.BP:
-                if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
-                    w = split_limbs(bitunpack(w, k), s.width)
-                y = bitparallel_matmul(x, w)
-            elif s.kernel == "fused_bitserial_matmul":
-                y = fused_bitserial_matmul(x, w, s.width)
-            elif s.repack == "bp2bs":
-                y = _bs(x, bitpack(w, s.width))
-            else:
-                y = _bs(x, w)
+            # one scope per step: the step's device ops carry its op name
+            with jax.named_scope(s.op):
+                x = (kops.thread_activations(out[src], m, k)
+                     if src is not None else xs[s.op])
+                w = ps[s.op]
+                if s.layout is Layout.BP:
+                    if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
+                        w = split_limbs(bitunpack(w, k), s.width)
+                    y = bitparallel_matmul(x, w)
+                elif s.kernel == "fused_bitserial_matmul":
+                    y = fused_bitserial_matmul(x, w, s.width)
+                elif s.repack == "bp2bs":
+                    y = _bs(x, bitpack(w, s.width))
+                else:
+                    y = _bs(x, w)
             out[s.op] = y
         return out
 
     fn = jax.jit(program, donate_argnums=(0,) if donate else ())
     # build = trace + lower + compile + first (warming) run; the run
     # consumes the placed entry buffers, which is why run() re-places
-    placed = {op: jnp.asarray(v) for op, v in entry.items()}
-    with warnings.catch_warnings():
-        # donation is best-effort: entries whose dtype/shape matches no
-        # output stay undonated, which is fine -- not worth a warning
-        # per compiled schedule
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        jax.block_until_ready(fn(placed, params))
-    compile_us = (time.perf_counter() - t0) * 1e6
+    with spans.span("schedule.first_run", key=key):
+        placed = {op: jnp.asarray(v) for op, v in entry.items()}
+        with warnings.catch_warnings():
+            # donation is best-effort: entries whose dtype/shape matches
+            # no output stay undonated, which is fine -- not worth a
+            # warning per compiled schedule
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
+            jax.block_until_ready(fn(placed, params))
+    compile_us = (spans.last("schedule.pack").dur_ns
+                  + spans.last("schedule.first_run").dur_ns) / 1e3
 
     return ScheduleExecutable(
         schedule=schedule, key=key, compile_us=compile_us,

@@ -36,6 +36,7 @@ from __future__ import annotations
 from collections import deque
 from typing import Optional, Sequence
 
+from repro import spans
 from repro.core.cost_model import Layout
 from repro.core.params import SystemParams, PAPER_SYSTEM
 from repro.core.transpose import transpose_cycles
@@ -295,6 +296,7 @@ def _step_feasibility(op, sys: SystemParams) -> tuple[bool, bool]:
     return op.rows_bp <= sys.array.rows, op.rows_bs <= sys.array.rows
 
 
+@spans.span("plan.compile")
 def compile_plan(workload, sys: SystemParams = PAPER_SYSTEM, *,
                  geometry: Optional[Geometry] = None,
                  initial_layout: Optional[Layout] = None,

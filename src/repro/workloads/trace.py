@@ -540,24 +540,27 @@ def trace_workload(fn: Callable, *example_args,
     """
     import jax
 
+    from repro import spans
+
     if scan_mode not in ("once", "unroll"):
         raise ValueError(f"scan_mode must be 'once' or 'unroll', "
                          f"got {scan_mode!r}")
-    closed = jax.make_jaxpr(fn)(*example_args)
-    paths = jax.tree_util.tree_flatten_with_path(example_args)[0]
-    t = _Tracer(precision_map=precision_map, default_width=default_width,
-                sys=sys, scan_mode=scan_mode, matmul_chunk=matmul_chunk,
-                matmul_working_set=(
-                    (lambda w: w * 8) if matmul_streamed_working_set
-                    else None))
-    invar_infos = [
-        _VarInfo(origins=frozenset({_format_path(path)}))
-        for path, _leaf in paths]
-    if len(invar_infos) != len(closed.jaxpr.invars):  # pragma: no cover
-        raise AssertionError(
-            f"flattened args ({len(invar_infos)}) != jaxpr invars "
-            f"({len(closed.jaxpr.invars)})")
-    t.trace(closed.jaxpr, invar_infos)
+    with spans.span("workload.trace", workload=name):
+        closed = jax.make_jaxpr(fn)(*example_args)
+        paths = jax.tree_util.tree_flatten_with_path(example_args)[0]
+        t = _Tracer(precision_map=precision_map, default_width=default_width,
+                    sys=sys, scan_mode=scan_mode, matmul_chunk=matmul_chunk,
+                    matmul_working_set=(
+                        (lambda w: w * 8) if matmul_streamed_working_set
+                        else None))
+        invar_infos = [
+            _VarInfo(origins=frozenset({_format_path(path)}))
+            for path, _leaf in paths]
+        if len(invar_infos) != len(closed.jaxpr.invars):  # pragma: no cover
+            raise AssertionError(
+                f"flattened args ({len(invar_infos)}) != jaxpr invars "
+                f"({len(closed.jaxpr.invars)})")
+        t.trace(closed.jaxpr, invar_infos)
     if not t.ops:
         raise ValueError(f"trace of {name!r} produced no ops "
                          "(nothing costable in the jaxpr)")

@@ -7,6 +7,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from repro import spans
 from repro.core.cost_model import Layout
 from repro.plan import (
     ExecutableCache,
@@ -107,11 +108,15 @@ def test_schedule_key_is_content_addressed():
 def test_compile_cost_charged_separately_from_run():
     _, sched = _hybrid_schedule("vgg13")
     exe = compile_schedule(sched, synth_inputs(sched))
-    assert exe.compile_us > 0
+    pack, first = spans.last("schedule.pack"), spans.last("schedule.first_run")
+    assert exe.compile_us == (pack.dur_ns + first.dur_ns) / 1e3 > 0
+    assert first.compiles >= 1           # the program is built here
     assert exe.params_bytes > 0          # weights are device-resident
     assert exe.n_measured == 3
-    warm_us = exe.time(reps=3)
-    assert 0 < warm_us < exe.compile_us  # steady state beats compile
+    exe.run()
+    warm = spans.last("schedule.run")
+    assert warm.compiles == 0
+    assert 0 < warm.dur_ns / 1e3 < exe.compile_us  # steady state beats compile
     summ = exe.summary()
     assert summ["key"] == exe.key and summ["n_measured"] == 3
 
