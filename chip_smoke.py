@@ -204,6 +204,7 @@ def main_path_phase(name: str, workload) -> None:
     print(f"  {name}: compile_us={exe.compile_us:.0f} "
           f"warm_run_us={warm_us:.0f} ({phases}) "
           f"params_bytes={exe.params_bytes} "
+          f"entry_bytes={exe.entry_bytes} "
           f"peak_bytes_in_use={peak} exact_steps="
           f"{len(want) - len(bad)}/{len(want)}", flush=True)
     check(not bad, f"{name}: steps differ from the XLA reference: {bad}")

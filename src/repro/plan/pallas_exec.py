@@ -21,10 +21,12 @@ that execution model (DESIGN.md Sec. 15):
   ``deps`` DAG (``kernels.ops.thread_activations``) -- real dataflow, so
   XLA cannot elide or reorder the chain, and synthetic operands exist
   only at entry steps.
-* Entry activations are donated (``donate_argnums``): XLA may alias
-  intermediates into their buffers.  The executable keeps host copies
-  and re-places them on every ``run()``, so re-running is always safe
-  and bit-identical.
+* Entry activations are device-resident state of the executable, as
+  the params are: placed once at compile time (no copy when the caller
+  already passed device arrays) and read in place by every ``run()``,
+  which fetches fresh results each call.  Nothing is donated: XLA
+  aliases an input only into an output of the same shape and dtype, and
+  entries are int8 ``[m, k]`` while every output is int32 ``[m, n]``.
 
 Per-step ``run_schedule`` stays authoritative as the differential
 reference: with the same threading it is bit-exact with the chained
@@ -41,7 +43,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
-import warnings
 from collections import OrderedDict
 from typing import Any, Optional
 
@@ -97,10 +98,10 @@ class ScheduleExecutable:
     """A :class:`PallasSchedule` compiled to one jitted device program.
 
     ``compile_us`` charges everything the steady state never pays again:
-    operand synthesis, weight conversion/packing into device residency
-    (span ``schedule.pack``), tracing, XLA compilation, and the first
-    (warming) execution (span ``schedule.first_run``).  ``run()`` is the
-    warm path.
+    operand synthesis, entry placement and weight conversion/packing
+    into device residency (span ``schedule.pack``), tracing, XLA
+    compilation, and the first (warming) execution (span
+    ``schedule.first_run``).  ``run()`` is the warm path.
     """
 
     schedule: PallasSchedule
@@ -110,35 +111,34 @@ class ScheduleExecutable:
     n_modelled: int
     entry_ops: tuple[str, ...]     #: steps consuming synthetic operands
     threaded: dict                 #: {consumer op: producer op}
-    donate: bool
     params_bytes: int              #: device-resident weight footprint
+    entry_bytes: int               #: device-resident entry footprint
     _fn: Any = dataclasses.field(repr=False)
     _params: Any = dataclasses.field(repr=False)
-    _entry: dict = dataclasses.field(repr=False)   #: host entry copies
+    _entry: dict = dataclasses.field(repr=False)   #: resident entries
     runs: int = 0
 
     def run(self) -> dict:
         """Execute the whole chained program once; returns
         {op: int32 [m, n] numpy result} for every measured step.
 
-        Entry activations are re-placed from host copies each call (the
-        program donates its input buffers), so running twice is safe and
-        bit-identical -- the donation-regression contract.  Each call
-        records the spans ``schedule.run`` > ``schedule.place`` /
-        ``.dispatch`` / ``.wait`` / ``.fetch`` and the counters
-        ``schedule.place_bytes`` / ``schedule.fetch_bytes``
-        (``repro.spans``).
+        Entry activations are resident on the device and read in place:
+        nothing is donated, so running twice is safe and bit-identical,
+        and each call's results are fresh arrays.  Each call records the
+        spans ``schedule.run`` > ``schedule.place`` / ``.dispatch`` /
+        ``.wait`` / ``.fetch`` and the counters ``schedule.place_bytes``
+        (entry bytes moved host->device: 0 when every entry is resident)
+        / ``schedule.fetch_bytes`` (``repro.spans``).
         """
         import jax
-        import jax.numpy as jnp
 
         with spans.span("schedule.run", key=self.key, call=self.runs):
             with spans.span("schedule.place"):
-                placed = {op: jnp.asarray(v) for op, v in self._entry.items()}
-            spans.count("schedule.place_bytes",
-                        sum(v.nbytes for v in self._entry.values()))
+                host = [v for v in self._entry.values()
+                        if not isinstance(v, jax.Array)]
+            spans.count("schedule.place_bytes", sum(v.nbytes for v in host))
             with spans.span("schedule.dispatch"):
-                out = self._fn(placed, self._params)
+                out = self._fn(self._entry, self._params)
             with spans.span("schedule.wait"):
                 jax.block_until_ready(out)
             with spans.span("schedule.fetch"):
@@ -155,18 +155,20 @@ class ScheduleExecutable:
                 "n_modelled": self.n_modelled,
                 "entry_ops": list(self.entry_ops),
                 "threaded": dict(self.threaded),
-                "donate": self.donate,
-                "params_bytes": self.params_bytes, "runs": self.runs}
+                "params_bytes": self.params_bytes,
+                "entry_bytes": self.entry_bytes, "runs": self.runs}
 
 
 def compile_schedule(schedule: PallasSchedule,
                      inputs: Optional[dict] = None, *, seed: int = 0,
-                     donate: bool = True,
                      key: Optional[str] = None) -> ScheduleExecutable:
     """Compile ``schedule`` into ONE jitted program (module doc).
 
     ``inputs``: optional ``{op: (x, w)}`` word-form operands (default:
-    :func:`plan.pallas.synth_inputs` with ``seed``).  Weights must be
+    :func:`plan.pallas.synth_inputs` with ``seed``).  Entry activations
+    that are already device arrays become the executable's resident
+    entries as they are (no copy); the executable never deletes them,
+    so the caller's arrays stay valid.  Weights must be
     canonical ``width``-bit words -- a boundary repack round-trips them
     through the plane form, which truncates any bits above ``width``
     (synthetic operands satisfy this by construction).
@@ -191,11 +193,11 @@ def compile_schedule(schedule: PallasSchedule,
 
         # ---- compile-time residency: convert/pack every weight once ----
         params: dict[str, Any] = {}
-        entry: dict[str, np.ndarray] = {}
+        entry: dict[str, Any] = {}
         for s in steps:
             x, w = inputs[s.op]
             if s.op not in producer:
-                entry[s.op] = np.asarray(x)
+                entry[s.op] = jnp.asarray(x)
             w = jnp.asarray(w)
             if s.layout is Layout.BP:
                 if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
@@ -210,8 +212,9 @@ def compile_schedule(schedule: PallasSchedule,
                 params[s.op] = w
             else:
                 params[s.op] = kops.pack_weights(w, s.width)
-        # the span covers the conversions, not only their dispatch
-        jax.block_until_ready(params)
+        # the span covers the placement and conversions, not only their
+        # dispatch
+        jax.block_until_ready((entry, params))
 
     def _bs(x, planes):
         # mirror kops.matmul_bs: bitpack zero-pads K to a multiple of 32
@@ -243,18 +246,11 @@ def compile_schedule(schedule: PallasSchedule,
             out[s.op] = y
         return out
 
-    fn = jax.jit(program, donate_argnums=(0,) if donate else ())
-    # build = trace + lower + compile + first (warming) run; the run
-    # consumes the placed entry buffers, which is why run() re-places
+    fn = jax.jit(program)
+    # build = trace + lower + compile + first (warming) run, on the same
+    # resident buffers every run() reads
     with spans.span("schedule.first_run", key=key):
-        placed = {op: jnp.asarray(v) for op, v in entry.items()}
-        with warnings.catch_warnings():
-            # donation is best-effort: entries whose dtype/shape matches
-            # no output stay undonated, which is fine -- not worth a
-            # warning per compiled schedule
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            jax.block_until_ready(fn(placed, params))
+        jax.block_until_ready(fn(entry, params))
     compile_us = (spans.last("schedule.pack").dur_ns
                   + spans.last("schedule.first_run").dur_ns) / 1e3
 
@@ -262,9 +258,10 @@ def compile_schedule(schedule: PallasSchedule,
         schedule=schedule, key=key, compile_us=compile_us,
         n_measured=len(steps),
         n_modelled=len(schedule.steps) - len(steps),
-        entry_ops=tuple(entry), threaded=producer, donate=donate,
+        entry_ops=tuple(entry), threaded=producer,
         params_bytes=sum(int(np.prod(p.shape)) * p.dtype.itemsize
                          for p in params.values()),
+        entry_bytes=sum(v.nbytes for v in entry.values()),
         _fn=fn, _params=params, _entry=entry)
 
 
@@ -274,7 +271,8 @@ class ExecutableCache:
 
     The serving steady state: every batch group whose representative
     lowers to an identical schedule (same steps, layouts, dims, repacks,
-    deps) reuses one compiled program and its device-resident weights.
+    deps) reuses one compiled program and its device-resident weights
+    and entry operands (``entry_bytes`` each, held on the device).
     Unlike :class:`serve.plan_cache.PlanCache` there is no disk tier --
     an executable holds live jitted closures and device buffers, so the
     cache is per-process by nature; the source fingerprint still
@@ -294,8 +292,7 @@ class ExecutableCache:
         self.puts = 0
 
     def get_or_compile(self, schedule: PallasSchedule,
-                       inputs: Optional[dict] = None, *, seed: int = 0,
-                       donate: bool = True
+                       inputs: Optional[dict] = None, *, seed: int = 0
                        ) -> tuple[ScheduleExecutable, str, bool]:
         """-> ``(executable, key, hit)``."""
         key = schedule_key(schedule, seed=seed,
@@ -306,8 +303,7 @@ class ExecutableCache:
             self.hits += 1
             return exe, key, True
         self.misses += 1
-        exe = compile_schedule(schedule, inputs, seed=seed,
-                               donate=donate, key=key)
+        exe = compile_schedule(schedule, inputs, seed=seed, key=key)
         self._mem[key] = exe
         self.puts += 1
         while len(self._mem) > self.capacity:
