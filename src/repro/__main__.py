@@ -808,7 +808,7 @@ def main(argv=None) -> int:
              "formulas (differential gate + CSV artifact)")
     p_diff.add_argument("archs", nargs="*",
                         help="arch ids (e.g. tinyllama_1_1b; default: "
-                             "all 10)")
+                             "every arch)")
     p_diff.add_argument("--tokens", type=int, default=4096,
                         help="decode batch / KV length (default 4096, the "
                              "arch/<id> operating point)")

@@ -21,6 +21,7 @@ ARCH_IDS = [
     "internvl2_2b",
     "recurrentgemma_2b",
     "whisper_small",
+    "mellum2_12b_a2_5b",
 ]
 
 
@@ -50,7 +51,7 @@ def get_config(arch_id: str) -> ArchConfig:
 
 
 def all_cells() -> list[tuple[str, str]]:
-    """The 40 (arch x shape) cells; long_500k marked runnable or skip."""
+    """Every (arch x shape) cell; long_500k marked runnable or skip."""
     cells = []
     for a in ARCH_IDS:
         for s in SHAPES:
@@ -87,9 +88,11 @@ def reduced_config(cfg: ArchConfig) -> ArchConfig:
         kw.update(n_experts=4, top_k=min(cfg.top_k, 2),
                   moe_every=cfg.moe_every)
         kw["n_layers"] = 2 * cfg.moe_every
+    if cfg.block_pattern:
+        kw.update(window=8, block_pattern=cfg.block_pattern)
+        kw["n_layers"] = len(cfg.block_pattern)  # one full block
     if cfg.family == "hybrid":
-        kw.update(window=8, lru_width=64,
-                  block_pattern=cfg.block_pattern, conv_width=cfg.conv_width)
+        kw.update(lru_width=64, conv_width=cfg.conv_width)
         kw["n_layers"] = len(cfg.block_pattern) + 2  # one full block + tail
     if cfg.family == "audio":
         kw.update(enc_layers=2, enc_seq=8)

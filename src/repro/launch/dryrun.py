@@ -58,7 +58,7 @@ import dataclasses as _dc  # noqa: E402
 
 
 def _period(cfg) -> int:
-    if cfg.family == "hybrid":
+    if cfg.block_pattern:
         return len(cfg.block_pattern)
     if cfg.n_experts and cfg.moe_every == 2:
         return 2
@@ -66,16 +66,13 @@ def _period(cfg) -> int:
 
 
 def _n_full_blocks(cfg) -> int:
-    if cfg.family == "hybrid":
-        per = len(cfg.block_pattern)
-        return cfg.n_layers // per
     return cfg.n_layers // _period(cfg)
 
 
 def depth_config(cfg, k: int):
-    """Same widths, k repeating blocks (tail kept for hybrids)."""
+    """Same widths, k repeating blocks (a pattern's tail kept)."""
     per = _period(cfg)
-    if cfg.family == "hybrid":
+    if cfg.block_pattern:
         tail = cfg.n_layers % per
         return _dc.replace(cfg, n_layers=per * k + tail)
     if cfg.family == "audio":

@@ -26,8 +26,6 @@ LEVER_ENV = {
     "attn_bf16": ("REPRO_ATTN_BF16", "1"),
     "fused_attn": ("REPRO_FUSED_ATTN", "1"),
     "ar_bf16": ("REPRO_AR_BF16", "1"),
-    "moe_bf16": ("REPRO_MOE_BF16_DISPATCH", "1"),
-    "moe_a2a": ("REPRO_MOE_A2A", "1"),
 }
 
 

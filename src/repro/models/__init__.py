@@ -1,4 +1,4 @@
-"""Model zoo: the 10 assigned architectures as composable JAX modules."""
+"""Model zoo: the assigned architectures as composable JAX modules."""
 from repro.models.base import (  # noqa: F401
     ArchConfig, ParamSpec, abstract_params, init_params, param_shardings,
 )

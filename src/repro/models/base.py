@@ -40,6 +40,10 @@ class ArchConfig:
     top_k: int = 0
     moe_every: int = 1  # MoE layer stride (llama4: every 2nd layer)
     capacity_factor: float = 1.25
+    # the experts this chip holds, [expert_lo, expert_lo + n_experts_here);
+    # the router still scores all n_experts (0 => every expert is held)
+    expert_lo: int = 0
+    n_experts_here: int = 0
     # --- SSM (mamba2) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -48,7 +52,9 @@ class ArchConfig:
     conv_width: int = 4
     # --- hybrid (recurrentgemma) ---
     window: int = 0  # local-attention window
-    block_pattern: tuple = ()  # e.g. ("rec", "rec", "attn")
+    # repeating sub-layer kinds, e.g. ("rec", "rec", "attn_local") or
+    # ("moe_local",) * 3 + ("moe",); see transformer.block_layout
+    block_pattern: tuple = ()
     lru_width: int = 0
     # --- enc-dec / multimodal frontends (stubs provide embeddings) ---
     enc_layers: int = 0
@@ -56,6 +62,9 @@ class ArchConfig:
     # --- common ---
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    # YaRN on the full-attention layers: (factor, original_max_positions,
+    # beta_fast, beta_slow, attention_factor); () => plain RoPE
+    rope_yarn: tuple = ()
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     # attention sharding policy: "heads" if n_heads % model_shards == 0
@@ -68,6 +77,10 @@ class ArchConfig:
     def padded_vocab(self) -> int:
         m = VOCAB_PAD_MULTIPLE
         return (self.vocab_size + m - 1) // m * m
+
+    @property
+    def experts_here(self) -> int:
+        return self.n_experts_here or self.n_experts
 
     @property
     def qkv_dim(self) -> int:

@@ -1,5 +1,5 @@
 """Shared neural layers: norms, RoPE, streaming flash attention, GQA
-projections, dense MLP, and grouped-dispatch MoE.
+projections, dense MLP, and index-dispatch MoE.
 
 All functions are pure (params passed explicitly) and insert activation
 sharding constraints via `repro.dist.sharding.shard` (no-ops off-mesh).
@@ -15,6 +15,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.dist.sharding import shard
@@ -43,16 +44,43 @@ def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
 
 # -------------------------------------------------------------------- RoPE
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, hd]; positions: [B, S] or [S]."""
+def yarn_frequencies(freqs: jax.Array, hd: int, theta: float,
+                     yarn: tuple) -> jax.Array:
+    """YaRN's blend of RoPE frequencies: dimensions that rotate fewer
+    than ``beta_slow`` times over the original context are interpolated
+    by ``factor``, those over ``beta_fast`` kept, a linear ramp between."""
+    factor, orig, beta_fast, beta_slow = yarn[:4]
+
+    def dim_of(rotations):
+        return (hd * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(dim_of(beta_fast)), 0)
+    hi = min(math.ceil(dim_of(beta_slow)), hd - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(hd // 2, dtype=np.float32) - lo) / (hi - lo),
+                   0.0, 1.0)
+    keep = jnp.asarray(1.0 - ramp)  # share of the original frequency
+    return freqs / factor * (1.0 - keep) + freqs * keep
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn: tuple = ()) -> jax.Array:
+    """x: [B, S, H, hd]; positions: [B, S] or [S].  ``yarn``: (factor,
+    original_max_positions, beta_fast, beta_slow, attention_factor), or ()
+    for plain RoPE; its attention factor scales cos and sin."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn:
+        freqs = yarn_frequencies(freqs, hd, theta, yarn)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -70,8 +98,13 @@ def flash_attention(
     kv_len: Optional[jax.Array] = None,  # [B] valid cache length
     window: int = 0,       # local attention window (0 => unbounded)
     chunk: int = 0,
+    k_pos: Optional[jax.Array] = None,   # [Sk] position held in each slot
 ) -> jax.Array:
     """GQA flash attention with KV-chunk streaming softmax.
+
+    ``k_pos`` gives each KV slot's true position (a ring buffer's slots
+    are not in position order); a negative entry marks an empty slot.
+    Without it, slot i holds position i.
 
     Memory: O(Sq * chunk) scores live, never O(Sq * Sk).
     """
@@ -103,9 +136,13 @@ def flash_attention(
 
     bf16_mm = util.attn_bf16_matmuls()
 
+    xs = (ks, vs, jnp.arange(n_chunks))
+    if k_pos is not None:
+        xs += (k_pos.reshape(n_chunks, chunk),)
+
     def body(carry, inp):
         m, l, o = carry
-        kc, vc, idx = inp
+        kc, vc, idx = inp[:3]
         base = idx * chunk
         with jax.named_scope("flash_internal"):
             # "flash_internal" tags the kernel-private tensors (scores,
@@ -120,14 +157,18 @@ def flash_attention(
                 s = jnp.einsum("bqkgd,bckd->bkgqc", qg,
                                kc.astype(jnp.float32))
             s = s * scale
-            k_pos = base + jnp.arange(chunk)  # [chunk]
             mask = jnp.ones((Sq, chunk), bool)
+            if k_pos is None:
+                kp = base + jnp.arange(chunk)  # [chunk]
+            else:
+                kp = inp[3]
+                mask &= kp[None, :] >= 0
             if causal:
-                mask &= q_pos[:, None] >= k_pos[None, :]
+                mask &= q_pos[:, None] >= kp[None, :]
             if window:
-                mask &= (q_pos[:, None] - k_pos[None, :]) < window
+                mask &= (q_pos[:, None] - kp[None, :]) < window
             if kv_len is not None:
-                mask = mask[None] & (k_pos[None, None, :]
+                mask = mask[None] & (kp[None, None, :]
                                      < kv_len[:, None, None])
                 s = jnp.where(mask[:, None, None], s, NEG_INF)
             else:
@@ -145,8 +186,7 @@ def flash_attention(
             o_new = o * alpha[..., None] + pv
         return (m_new, l_new, o_new), None
 
-    (m, l, o), _ = util.scan(body, (m0, l0, o0),
-                             (ks, vs, jnp.arange(n_chunks)))
+    (m, l, o), _ = util.scan(body, (m0, l0, o0), xs)
     out = o / jnp.maximum(l, 1e-20)[..., None]
     out = jnp.moveaxis(out, 3, 1).reshape(B, Sq, H, hd)
     return out.astype(q.dtype)
@@ -180,13 +220,25 @@ def attention_reference(q, k, v, *, causal=True, q_offset=0, kv_len=None,
 
 # --------------------------------------------------------- GQA attention ---
 
+def ring_positions(written, slots: int) -> jax.Array:
+    """[slots] position held in each slot of a ring buffer after positions
+    ``0 .. written - 1`` were written at ``position mod slots``; -1 where
+    nothing was written yet."""
+    last = written - 1
+    pos = last - jnp.mod(last - jnp.arange(slots), slots)
+    return jnp.where(pos >= 0, pos, -1)
+
+
 def gqa_attention(cfg, p, x, *, positions, cache=None, layer_name="attn",
-                  window: int = 0, chunk: int = 0):
+                  window: int = 0, chunk: int = 0, yarn: tuple = ()):
     """Full attention sub-block: QKV proj -> RoPE -> flash attn -> O proj.
 
     cache: None for train/prefill-from-scratch, else dict with
     {"k": [B, Smax, K, hd], "v": ..., "len": [B]} -- decode appends at
-    position `len` and attends over the prefix.
+    position `len` and attends over the prefix.  With ``window`` set the
+    cache is a ring buffer of ``Smax`` slots (``Smax <= window``, or the
+    whole context): position ``t`` lives in slot ``t mod Smax`` and the
+    mask reads each slot's true position.
     Returns (out, new_cache).
     """
     B, S, D = x.shape
@@ -202,8 +254,8 @@ def gqa_attention(cfg, p, x, *, positions, cache=None, layer_name="attn",
     q = q.reshape(B, S, H, hd)
     kk = kk.reshape(B, S, K, hd)
     vv = vv.reshape(B, S, K, hd)
-    q = rope(q, positions, cfg.rope_theta)
-    kk = rope(kk, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta, yarn)
+    kk = rope(kk, positions, cfg.rope_theta, yarn)
 
     if cache is None:
         if policy == "heads":
@@ -218,6 +270,12 @@ def gqa_attention(cfg, p, x, *, positions, cache=None, layer_name="attn",
         out = flash_attention(q, kk, vv, causal=True, window=window,
                               chunk=chunk)
         new_cache = None
+    elif window:
+        kk = shard(kk, "batch", None, None, None).astype(cache["k"].dtype)
+        vv = shard(vv, "batch", None, None, None).astype(cache["v"].dtype)
+        if policy == "heads":
+            q = shard(q, "batch", None, "model", None)
+        out, new_cache = _ring_attention(q, kk, vv, cache, window, chunk)
     else:
         # decode: append S (=1) new token(s) at position cache["len"].
         # k/v arrive model-sharded from the QKV split; constrain them to the
@@ -248,6 +306,41 @@ def gqa_attention(cfg, p, x, *, positions, cache=None, layer_name="attn",
     return out, new_cache
 
 
+def _ring_attention(q, kk, vv, cache, window: int, chunk: int):
+    """Decode ``S`` new tokens against a ring-buffer cache -> (out, cache).
+
+    One token (decode) is written first, at ``len mod slots``, and attends
+    over the ring.  Several (prefill) attend over the old ring followed by
+    themselves, and then the last ``min(S, slots)`` of them are written.
+    """
+    S = q.shape[1]
+    slots = cache["k"].shape[1]
+    idx = cache["len"][0]  # uniform decode step across batch
+    if S == 1:
+        at = jnp.mod(idx, slots)
+        ck = lax.dynamic_update_slice_in_dim(cache["k"], kk, at, axis=1)
+        cv = lax.dynamic_update_slice_in_dim(cache["v"], vv, at, axis=1)
+        ck = shard(ck, "batch", None, None, None)
+        cv = shard(cv, "batch", None, None, None)
+        out = flash_attention(q, ck, cv, causal=True, q_offset=idx,
+                              window=window, chunk=chunk,
+                              k_pos=ring_positions(idx + 1, slots))
+    else:
+        k_pos = jnp.concatenate([ring_positions(idx, slots),
+                                 idx + jnp.arange(S)])
+        out = flash_attention(
+            q, jnp.concatenate([cache["k"], kk], axis=1),
+            jnp.concatenate([cache["v"], vv], axis=1), causal=True,
+            q_offset=idx, window=window, chunk=chunk, k_pos=k_pos)
+        n = min(S, slots)
+        at = jnp.mod(idx + S - n + jnp.arange(n), slots)
+        ck = shard(cache["k"].at[:, at].set(kk[:, S - n:]),
+                   "batch", None, None, None)
+        cv = shard(cache["v"].at[:, at].set(vv[:, S - n:]),
+                   "batch", None, None, None)
+    return out, {"k": ck, "v": cv, "len": cache["len"] + S}
+
+
 # ------------------------------------------------------------- dense MLP ---
 
 def swiglu_mlp(p, x):
@@ -267,66 +360,68 @@ def gelu_mlp(p, x):
     return shard(out, "batch", None, None)
 
 
-# ----------------------------------------------------- MoE (grouped EP) ----
+# ------------------------------------------- MoE (index dispatch, EP) ------
 
-def moe_block(cfg, p, x, *, group_size: int = 512):
-    """Top-k MoE with grouped GShard dispatch.
+def expert_capacity(cfg, tokens: int) -> int:
+    """Slots per expert: ``ceil(tokens * top_k * capacity_factor / E)``."""
+    return int(math.ceil(tokens * cfg.top_k * cfg.capacity_factor
+                         / cfg.n_experts))
 
-    Experts are sharded over the `data` axis (EP) and their FF dim over
-    `model` (TP); token groups bound the dispatch-einsum cost to
-    O(tokens * group_size) instead of O(tokens * seq).
+
+def moe_block(cfg, p, x):
+    """Top-k MoE over the experts this chip holds, dispatched by index.
+
+    The router scores all ``cfg.n_experts`` experts; the gate is the
+    softmax over the top-k logits (the top-k probabilities renormalised).
+    Each (token, choice) pair whose expert lies in the held range
+    ``[expert_lo, expert_lo + experts_here)`` takes the next free one of
+    that expert's :func:`expert_capacity` slots, in token order; pairs
+    past capacity are dropped.  The slot tables come from a cumsum and
+    scatters, the tokens reach their slots by a gather, each held expert
+    runs its SwiGLU on its own slots with its own weights, and the
+    gate-weighted results are scatter-added back to their tokens.  What
+    absent experts would add is left out: the output is this chip's part
+    of the layer.  Held experts are sharded over ``data`` (EP), their FF
+    dim over ``model`` (TP).
+
+    Returns (out [B, S, D], pairs dropped past capacity: int32 scalar).
     """
     B, S, D = x.shape
-    E, k, cf = cfg.n_experts, cfg.top_k, cfg.capacity_factor
-    tokens = x.reshape(B * S, D)
-    T = min(group_size, B * S)
-    while (B * S) % T:
-        T //= 2
-    G = (B * S) // T
-    xt = tokens.reshape(G, T, D)
-    xt = shard(xt, "batch", None, None)
+    k, n_here = cfg.top_k, cfg.experts_here
+    T = B * S
+    C = expert_capacity(cfg, T)
+    xt = shard(x.reshape(T, D), "batch", None)
 
     logits = (xt @ p["router"].astype(xt.dtype)).astype(jnp.float32)
-    gate, sel = lax.top_k(logits, k)  # [G, T, k]
-    gate = jax.nn.softmax(gate, axis=-1)
+    top, sel = lax.top_k(logits, k)                       # [T, k]
+    gate = jax.nn.softmax(top, axis=-1).reshape(T * k)
+    rel = sel.reshape(T * k) - cfg.expert_lo              # pairs, token order
+    held = (rel >= 0) & (rel < n_here)
+    hit = rel[:, None] == jnp.arange(n_here)[None, :]     # [T*k, n_here]
+    order = jnp.cumsum(hit, axis=0, dtype=jnp.int32) - 1  # slot at each expert
+    slot = jnp.take_along_axis(
+        order, jnp.clip(rel, 0, n_here - 1)[:, None], axis=1)[:, 0]
+    keep = held & (slot < C)
+    dropped = jnp.sum(held & ~keep, dtype=jnp.int32)
 
-    C = int(math.ceil(T * k * cf / E))
-    # position bookkeeping in f32 (counts up to T exceed bf16 integer
-    # precision); the dispatch/combine one-hots themselves hold exactly
-    # representable 0/1 (and gate weights), so they may live in bf16
-    # (REPRO_MOE_BF16_DISPATCH=1) -- halving the [G,T,E,C] tensor traffic.
-    from repro import util as _util
-    ddt = x.dtype if _util.moe_bf16_dispatch() else jnp.float32
-    onehot = jax.nn.one_hot(sel, E, dtype=jnp.float32)      # [G, T, k, E]
-    per_te = onehot.sum(2)                                  # [G, T, E] (0/1)
-    pos_te = jnp.cumsum(per_te, axis=1) - per_te            # exclusive count
-    pos_k = jnp.einsum("gte,gtke->gtk", pos_te, onehot)     # slot per choice
-    keep_k = (pos_k < C).astype(jnp.float32)                # capacity drop
-    keep = (keep_k[..., None] * onehot).astype(ddt)         # [G, T, k, E]
-    posc = (jax.nn.one_hot(pos_k, C, dtype=jnp.float32)
-            * keep_k[..., None]).astype(ddt)
-    disp = jnp.einsum("gtke,gtkc->gtec", keep, posc)        # [G, T, E, C]
-    comb = jnp.einsum("gtk,gtke,gtkc->gtec", gate.astype(ddt), keep, posc)
+    # (expert, slot) -> its token (T, a zero row, where empty) and gate
+    row = jnp.where(keep, rel, n_here)                    # n_here: dropped
+    tok = jnp.broadcast_to(jnp.arange(T)[:, None], (T, k)).reshape(T * k)
+    src = jnp.full((n_here, C), T, jnp.int32).at[row, slot].set(
+        tok, mode="drop")
+    weight = jnp.zeros((n_here, C), jnp.float32).at[row, slot].set(
+        gate, mode="drop")
 
-    xe = jnp.einsum("gtec,gtd->gecd", disp.astype(x.dtype), xt)
-    if _util.moe_two_step_reshard():
-        # materialize token-sharded first, THEN exchange g(data) -> e(data):
-        # a pure dim exchange SPMD lowers as all-to-all instead of
-        # all-reduce + all-gather
-        xe = shard(xe, "batch", None, None, None)
-    xe = shard(xe, None, "data", None, None)
-    h = jnp.einsum("gecd,edf->gecf", xe, p["w_gate"])
-    u = jnp.einsum("gecd,edf->gecf", xe, p["w_up"])
-    h = shard(h, None, "data", None, "model")
-    u = shard(u, None, "data", None, "model")
+    xe = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])[src]
+    xe = shard(xe, "data", None, None)                    # [n_here, C, D]
+    h = shard(jnp.matmul(xe, p["w_gate"]), "data", None, "model")
+    u = shard(jnp.matmul(xe, p["w_up"]), "data", None, "model")
     a = jax.nn.silu(h.astype(jnp.float32)).astype(x.dtype) * u
-    ye = jnp.einsum("gecf,efd->gecd", a, p["w_down"])
-    ye = shard(ye, None, "data", None, None)
-    if _util.moe_two_step_reshard():
-        ye = shard(ye, "batch", None, None, None)  # e(data) -> g(data) A2A
-    out = jnp.einsum("gtec,gecd->gtd", comb.astype(x.dtype), ye)
-    out = shard(out, "batch", None, None)
-    return out.reshape(B, S, D)
+    ye = shard(jnp.matmul(a, p["w_down"]), "data", None, None)
+    ye = ye.astype(jnp.float32) * weight[..., None]
+    out = jnp.zeros((T + 1, D), jnp.float32).at[src].add(ye)[:T]
+    out = shard(out.astype(x.dtype), "batch", None)
+    return out.reshape(B, S, D), dropped
 
 
 # ----------------------------------------------------------- lm head/loss --

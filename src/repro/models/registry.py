@@ -28,11 +28,12 @@ def model_fns(cfg: ArchConfig) -> SimpleNamespace:
 
 def traced_workload(cfg: ArchConfig, *, tokens: int = 4096,
                     phase: str = "decode", weight_bits: int = 4,
-                    scan_mode: str = "once"):
+                    scan_mode: str = "once", kv_len: int | None = None):
     """Trace the family's real forward pass into a Workload DAG.
 
     ``phase="decode"``: one decode step over ``tokens`` concurrent
-    sequences with a ``tokens``-long KV cache -- the operating point of
+    sequences with a ``kv_len``-long KV cache (default ``tokens``; local
+    layers hold ``min(window, kv_len)`` slots) -- the operating point of
     the hand-written ``arch/<id>`` serving formulas, so the two are
     directly comparable (``repro.workloads.trace_diff``).
     ``phase="prefill"``: ``forward_hidden`` over one ``tokens``-long
@@ -42,7 +43,8 @@ def traced_workload(cfg: ArchConfig, *, tokens: int = 4096,
     models trace without allocating a single parameter.  Weight matrices
     (>=2-D leaves at the model dtype) resolve to ``weight_bits``; the
     RG-LRU gate matrices stay at model precision, matching the 16-bit
-    ``rg_lru_gates`` formula op.
+    ``rg_lru_gates`` formula op.  Each held expert's product with its own
+    weights is one matmul op (``Op.expert``).
     """
     import jax
     import jax.numpy as jnp
@@ -58,9 +60,13 @@ def traced_workload(cfg: ArchConfig, *, tokens: int = 4096,
     pmap = param_path_widths(params, weight_bits=weight_bits,
                              dtype=cfg.dtype,
                              exclude=("a_gate", "input_gate"))
+    experts = ()
+    if cfg.n_experts:
+        from repro.models.transformer import expert_param_paths
+        experts = tuple(f"0/{p}" for p in expert_param_paths(cfg))
     if phase == "decode":
-        cache = abstract_params(
-            fns.cache_structure(cfg, batch=tokens, max_len=tokens))
+        cache = abstract_params(fns.cache_structure(
+            cfg, batch=tokens, max_len=tokens if kv_len is None else kv_len))
         tok = jax.ShapeDtypeStruct((tokens, 1), jnp.int32)
 
         def fn(p, c, t):
@@ -77,9 +83,11 @@ def traced_workload(cfg: ArchConfig, *, tokens: int = 4096,
         args = (params, batch)
     return trace_workload(
         fn, *args, precision_map=pmap, name=f"traced/{cfg.name}",
-        source="traced", scan_mode=scan_mode,
+        source="traced", scan_mode=scan_mode, expert_paths=experts,
         description=(f"{cfg.name} jaxpr-traced {phase} step "
-                     f"({tokens} tokens, int{weight_bits} weights)"))
+                     f"({tokens} tokens, int{weight_bits} weights"
+                     + ("" if kv_len is None else f", {kv_len} KV")
+                     + ")"))
 
 
 def param_count(cfg: ArchConfig) -> int:

@@ -1,11 +1,12 @@
 """Decoder-only transformer family: dense (yi, tinyllama, mistral-nemo,
-stablelm), MoE (dbrx, llama4-maverick), and the LM backbone reused by the
-VLM/audio/hybrid models.
+stablelm), MoE (dbrx, llama4-maverick, mellum2), and the LM backbone reused
+by the VLM/audio/hybrid models.
 
 Layers are stacked along a leading block axis and executed with `lax.scan`
 (small HLO, O(1) compile cost in depth). A block is a repeating pattern of
-sub-layers (`block_layout`), so MoE-every-2 (llama4) and hybrid patterns
-(recurrentgemma) reuse the same machinery.
+sub-layers (`block_layout`), so MoE-every-2 (llama4), sliding/full attention
+patterns (mellum2) and hybrid patterns (recurrentgemma) reuse the same
+machinery.
 """
 from __future__ import annotations
 
@@ -22,10 +23,21 @@ from repro.models.base import ArchConfig, ParamSpec
 
 # ------------------------------------------------------------- structure ---
 
+#: sub-layer kinds -> (attention kind, MLP kind); 'local' attention keeps a
+#: ``window``-slot ring-buffer cache, 'full' one of the whole context
+SUBLAYERS = {
+    "dense": ("full", "dense"),
+    "moe": ("full", "moe"),
+    "attn_local": ("local", "dense"),
+    "moe_local": ("local", "moe"),
+    "rec": ("rec", "dense"),
+}
+
+
 def block_layout(cfg: ArchConfig) -> tuple[list[str], list[str]]:
-    """(repeating block layout, tail layout). Entries: 'dense' | 'moe' |
-    'rec' | 'attn_local'."""
-    if cfg.family == "hybrid":
+    """(repeating block layout, tail layout). Entries: keys of
+    :data:`SUBLAYERS`; ``cfg.block_pattern`` gives them per layer."""
+    if cfg.block_pattern:
         pat = list(cfg.block_pattern)
         n_full = cfg.n_layers // len(pat)
         tail_n = cfg.n_layers - n_full * len(pat)
@@ -59,17 +71,34 @@ def _mlp_params(cfg: ArchConfig, n: int) -> dict:
 
 
 def _moe_params(cfg: ArchConfig, n: int) -> dict:
-    D, F, E, dt = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.dtype
+    """The router scores all ``n_experts``; expert weights are held for
+    ``experts_here`` of them."""
+    D, F, dt = cfg.d_model, cfg.d_ff, cfg.dtype
+    E, Eh = cfg.n_experts, cfg.experts_here
     return {
         "router": ParamSpec((n, D, E), jnp.float32, (None, None, None),
                             init="small"),
-        "w_gate": ParamSpec((n, E, D, F), dt, (None, "data", None, "model"),
+        "w_gate": ParamSpec((n, Eh, D, F), dt, (None, "data", None, "model"),
                             fan_in=D),
-        "w_up": ParamSpec((n, E, D, F), dt, (None, "data", None, "model"),
+        "w_up": ParamSpec((n, Eh, D, F), dt, (None, "data", None, "model"),
                           fan_in=D),
-        "w_down": ParamSpec((n, E, F, D), dt, (None, "data", "model", None),
+        "w_down": ParamSpec((n, Eh, F, D), dt, (None, "data", "model", None),
                             fan_in=F),
     }
+
+
+#: expert weight leaves of an MoE sub-layer's ``mlp`` params
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def expert_param_paths(cfg: ArchConfig) -> tuple[str, ...]:
+    """Key paths (``blocks/<i>/mlp/<leaf>``, ``tail/...``) of every expert
+    weight in :func:`param_structure`."""
+    layout, tail = block_layout(cfg)
+    return tuple(f"{group}/{i}/mlp/{leaf}"
+                 for group, kinds in (("blocks", layout), ("tail", tail))
+                 for i, kind in enumerate(kinds)
+                 if SUBLAYERS[kind][1] == "moe" for leaf in EXPERT_LEAVES)
 
 
 def _rec_params(cfg: ArchConfig, n: int) -> dict:
@@ -92,17 +121,11 @@ def _rec_params(cfg: ArchConfig, n: int) -> dict:
 def _sublayer_params(cfg: ArchConfig, kind: str, n: int) -> dict:
     D, dt = cfg.d_model, cfg.dtype
     ln = lambda: ParamSpec((n, D), dt, (None, None), init="ones")  # noqa: E731
-    if kind in ("dense", "moe"):
-        body = _mlp_params(cfg, n) if kind == "dense" else _moe_params(cfg, n)
-        return {"ln1": ln(), "attn": _attn_params(cfg, n),
-                "ln2": ln(), "mlp": body}
-    if kind == "attn_local":
-        return {"ln1": ln(), "attn": _attn_params(cfg, n),
-                "ln2": ln(), "mlp": _mlp_params(cfg, n)}
-    if kind == "rec":
-        return {"ln1": ln(), "rec": _rec_params(cfg, n),
-                "ln2": ln(), "mlp": _mlp_params(cfg, n)}
-    raise ValueError(kind)
+    attn, mlp = SUBLAYERS[kind]
+    mixer = ({"rec": _rec_params(cfg, n)} if attn == "rec"
+             else {"attn": _attn_params(cfg, n)})
+    body = _moe_params(cfg, n) if mlp == "moe" else _mlp_params(cfg, n)
+    return {"ln1": ln(), **mixer, "ln2": ln(), "mlp": body}
 
 
 def param_structure(cfg: ArchConfig):
@@ -127,7 +150,12 @@ def param_structure(cfg: ArchConfig):
 # ----------------------------------------------------------------- cache ---
 
 def cache_structure(cfg: ArchConfig, batch: int, max_len: int):
-    """Decode cache as a ParamSpec pytree (zeros init / abstract dry-run)."""
+    """Decode cache as a ParamSpec pytree (zeros init / abstract dry-run).
+
+    Full-attention layers hold ``max_len`` positions; local ones a ring
+    buffer of ``min(window, max_len)`` slots.  MoE configs add
+    ``moe_dropped``: the (token, expert) pairs the last step dropped past
+    capacity, summed over layers."""
     layout, tail = block_layout(cfg)
     per = len(layout)
     n_blocks = (cfg.n_layers - len(tail)) // per
@@ -142,25 +170,24 @@ def cache_structure(cfg: ArchConfig, batch: int, max_len: int):
         }
 
     def sub(kind, n):
-        if kind in ("dense", "moe"):
+        attn = SUBLAYERS[kind][0]
+        if attn == "full":
             return kv(n, max_len)
-        if kind == "attn_local":
-            # full-length cache with window enforced by masking; a ring
-            # buffer (O(window) memory) is a recorded perf-iteration lever
-            return kv(n, max_len)
-        if kind == "rec":
-            W = cfg.lru_width
-            return {
-                "h": ParamSpec((n, batch, W), jnp.float32,
-                               (None, "batch", "model"), init="zeros"),
-                "conv": ParamSpec((n, batch, cfg.conv_width - 1, W), dt,
-                                  (None, "batch", None, "model"),
-                                  init="zeros"),
-            }
-        raise ValueError(kind)
+        if attn == "local":
+            return kv(n, min(cfg.window, max_len))
+        W = cfg.lru_width
+        return {
+            "h": ParamSpec((n, batch, W), jnp.float32,
+                           (None, "batch", "model"), init="zeros"),
+            "conv": ParamSpec((n, batch, cfg.conv_width - 1, W), dt,
+                              (None, "batch", None, "model"),
+                              init="zeros"),
+        }
 
     st = {"len": ParamSpec((batch,), jnp.int32, ("batch",), init="zeros"),
           "blocks": [sub(kind, n_blocks) for kind in layout]}
+    if cfg.n_experts:
+        st["moe_dropped"] = ParamSpec((), jnp.int32, (), init="zeros")
     if tail:
         st["tail"] = [sub(kind, 1) for kind in tail]
     return st
@@ -172,38 +199,41 @@ def _take_layer(tree, i):
     return jax.tree.map(lambda x: x[i], tree)
 
 
-def _apply_sublayer(cfg, kind, p, x, *, positions, cache, window_override=None):
-    """One residual sub-layer. Returns (x, new_cache)."""
+def _apply_sublayer(cfg, kind, p, x, *, positions, cache):
+    """One residual sub-layer. Returns (x, new_cache, pairs the MoE
+    dropped past capacity, or None without an MoE)."""
     from repro.models import recurrent  # late import (rec blocks)
 
+    attn, mlp = SUBLAYERS[kind]
     new_cache = cache
-    if kind == "rec":
+    if attn == "rec":
         h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
         h, new_cache = recurrent.rg_lru_block(cfg, p["rec"], h, cache=cache)
         x = x + h
     else:
-        window = cfg.window if kind == "attn_local" else 0
-        if window_override is not None:
-            window = window_override
+        local = attn == "local"
         h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
         attn_cache = None if cache is None else \
             {"k": cache["k"], "v": cache["v"], "len": cache["len"]}
-        h, kv_new = L.gqa_attention(cfg, p["attn"], h, positions=positions,
-                                    cache=attn_cache, window=window)
+        h, kv_new = L.gqa_attention(
+            cfg, p["attn"], h, positions=positions, cache=attn_cache,
+            window=cfg.window if local else 0,
+            yarn=() if local else cfg.rope_yarn)
         if kv_new is not None:
             new_cache = {"k": kv_new["k"], "v": kv_new["v"]}
         x = x + h
     if util.bf16_allreduce_barrier():
         x = lax.optimization_barrier(x)  # keep TP psums in bf16
     h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if kind == "moe":
-        h = L.moe_block(cfg, p["mlp"], h)
+    dropped = None
+    if mlp == "moe":
+        h, dropped = L.moe_block(cfg, p["mlp"], h)
     else:
         h = L.swiglu_mlp(p["mlp"], h)
     x = x + h
     if util.bf16_allreduce_barrier():
         x = lax.optimization_barrier(x)
-    return x, new_cache
+    return x, new_cache, dropped
 
 
 def _run_blocks(cfg, params, x, *, positions, cache=None):
@@ -211,7 +241,7 @@ def _run_blocks(cfg, params, x, *, positions, cache=None):
     layout, tail = block_layout(cfg)
 
     def block_fn(xc, blk):
-        x, step_len = xc
+        x, step_len, dropped = xc
         blk_params, blk_cache = blk
         new_caches = []
         for kind, p, c in zip(layout, blk_params,
@@ -219,18 +249,21 @@ def _run_blocks(cfg, params, x, *, positions, cache=None):
             if c is not None:
                 c = dict(c)
                 c["len"] = step_len
-            x, nc = _apply_sublayer(cfg, kind, p, x, positions=positions,
-                                    cache=c)
+            x, nc, d = _apply_sublayer(cfg, kind, p, x, positions=positions,
+                                       cache=c)
+            if d is not None:
+                dropped = dropped + d
             if nc is not None:
                 nc = {k: v for k, v in nc.items() if k != "len"}
             new_caches.append(nc)
-        return (x, step_len), new_caches
+        return (x, step_len, dropped), new_caches
 
     blk_caches = cache["blocks"] if cache is not None else None
     step_len = cache["len"] if cache is not None else None
+    dropped = jnp.int32(0)
     if cache is None:
         def scan_fn(x, blk_params):
-            (x, _), _ = block_fn((x, None), (blk_params, None))
+            (x, _, _), _ = block_fn((x, None, dropped), (blk_params, None))
             return x, None
         if util.remat_enabled():
             scan_fn = jax.checkpoint(
@@ -240,10 +273,10 @@ def _run_blocks(cfg, params, x, *, positions, cache=None):
     else:
         def scan_fn(carry, xs):
             blk_params, blk_cache = xs
-            (x, sl), ncs = block_fn(carry, (blk_params, blk_cache))
-            return (x, sl), ncs
-        (x, _), new_blk_caches = util.scan(
-            scan_fn, (x, step_len), (params["blocks"], blk_caches))
+            return block_fn(carry, (blk_params, blk_cache))
+        (x, _, dropped), new_blk_caches = util.scan(
+            scan_fn, (x, step_len, dropped),
+            (params["blocks"], blk_caches))
         new_cache = {"len": step_len + x.shape[1],
                      "blocks": new_blk_caches}
 
@@ -256,13 +289,17 @@ def _run_blocks(cfg, params, x, *, positions, cache=None):
             if tail_caches is not None:
                 c = dict(_take_layer(tail_caches[i], 0))
                 c["len"] = step_len
-            x, nc = _apply_sublayer(cfg, kind, p, x, positions=positions,
-                                    cache=c)
+            x, nc, d = _apply_sublayer(cfg, kind, p, x, positions=positions,
+                                       cache=c)
             if nc is not None:  # restore the leading block axis
                 nc = {k: v[None] for k, v in nc.items() if k != "len"}
+            if d is not None:
+                dropped = dropped + d
             new_tail.append(nc)
         if new_cache is not None:
             new_cache["tail"] = new_tail
+    if new_cache is not None and cfg.n_experts:
+        new_cache["moe_dropped"] = dropped
     return x, new_cache
 
 

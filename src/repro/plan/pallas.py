@@ -38,6 +38,7 @@ silently clamped measurement.
 from __future__ import annotations
 
 import dataclasses
+import math
 import statistics
 import time
 from typing import Optional
@@ -78,6 +79,7 @@ class PallasStep:
     dims: Optional[tuple[int, int, int]] = None         #: true (m, k, n)
     padded_dims: Optional[tuple[int, int, int]] = None  #: as padded/run
     note: str = ""
+    expert: bool = False     #: one expert's product (``Op.expert``)
 
     @property
     def measured(self) -> bool:
@@ -216,10 +218,25 @@ def lower_plan_pallas(plan: LayoutPlan, workload, *,
             op=op.name, kind=op.kind, layout=layout, width=op.width,
             kernel=kernel, repack=repack, dims=(m, k, n),
             padded_dims=t.padded_dims,
-            note="repack folded into fused kernel" if fused else ""))
+            note="repack folded into fused kernel" if fused else "",
+            expert=op.expert))
+    _count_expert_work(steps)
     return PallasSchedule(workload=workload.name, steps=tuple(steps),
                           fuse_pack=fuse_pack,
                           deps=tuple(workload.edges()))
+
+
+def _count_expert_work(steps) -> None:
+    """Counters of the measured expert steps, once per lowering:
+    ``lower.expert_steps``, ``lower.expert_macs`` (true MACs) and
+    ``lower.expert_mxu_work`` (padded MACs x :func:`mxu_passes`)."""
+    experts = [s for s in steps if s.expert and s.measured]
+    spans.count("lower.expert_steps", len(experts))
+    spans.count("lower.expert_macs",
+                sum(math.prod(s.dims) for s in experts))
+    spans.count("lower.expert_mxu_work",
+                sum(math.prod(s.padded_dims) * mxu_passes(s.layout, s.width)
+                    for s in experts))
 
 
 def synth_inputs(schedule: PallasSchedule, seed: int = 0) -> dict:

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.dist.sharding import place_on_mesh, use_mesh
 from repro.models import init_params, registry
 from repro.models.base import ArchConfig
@@ -40,6 +41,14 @@ class ServeSession:
         cache = init_params(structure, jax.random.key(0))
         return place_on_mesh(cache, structure, self.mesh)
 
+    def _step(self, cache, tokens):
+        """One decode call; an MoE model's pairs dropped past capacity are
+        recorded as counter ``moe.dropped_pairs``."""
+        logits, cache = self._decode(self.params, cache, tokens)
+        if "moe_dropped" in cache:
+            spans.count("moe.dropped_pairs", int(cache["moe_dropped"]))
+        return logits, cache
+
     def generate(self, prompts: Sequence[Sequence[int]],
                  max_new_tokens: int = 8) -> list[list[int]]:
         B = len(prompts)
@@ -50,8 +59,7 @@ class ServeSession:
         cache = self._empty_cache(B)
         out = [list(p) for p in prompts]
         with use_mesh(self.mesh):
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(toks))  # prefill
+            logits, cache = self._step(cache, jnp.asarray(toks))  # prefill
             cur = jnp.argmax(logits[:, -1:, : self.cfg.vocab_size], axis=-1
                              ).astype(jnp.int32)
             for _ in range(max_new_tokens):
@@ -60,7 +68,7 @@ class ServeSession:
                 step_toks = np.asarray(cur)[:, 0]
                 for o, t in zip(out, step_toks.tolist()):
                     o.append(t)
-                logits, cache = self._decode(self.params, cache, cur)
+                logits, cache = self._step(cache, cur)
                 cur = jnp.argmax(logits[:, -1:, : self.cfg.vocab_size],
                                  axis=-1).astype(jnp.int32)
         return out

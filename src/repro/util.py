@@ -107,17 +107,6 @@ def fused_attention_accounting() -> bool:
     return os.environ.get("REPRO_FUSED_ATTN", "") == "1"
 
 
-def moe_bf16_dispatch() -> bool:
-    """Perf lever: bf16 dispatch/combine one-hots (exactly representable)."""
-    return os.environ.get("REPRO_MOE_BF16_DISPATCH", "") == "1"
-
-
-def moe_two_step_reshard() -> bool:
-    """Perf lever: explicit g(data)->e(data) dim exchange so SPMD emits
-    all-to-all for MoE token routing instead of all-reduce+all-gather."""
-    return os.environ.get("REPRO_MOE_A2A", "") == "1"
-
-
 def bf16_allreduce_barrier() -> bool:
     """Perf lever: optimization_barrier after residual adds, preventing XLA
     from hoisting the rms_norm f32 convert above the row-parallel psum

@@ -95,6 +95,8 @@ class Op:
     mixed_precision: bool = False
     working_set_bits: Optional[int] = None
     latency_critical: bool = False
+    # -- provenance ------------------------------------------------------
+    expert: bool = False    # matmul: one expert's product with its weights
 
     def __post_init__(self):
         if self.kind not in OP_KINDS:

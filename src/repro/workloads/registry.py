@@ -557,12 +557,13 @@ def arch_workload(cfg, *, tokens: int = 4096,
                     description=f"{cfg.name} int{weight_bits} serving")
 
 
-#: the 10 serving architectures (each registered as arch/<id> and
+#: the 11 serving architectures (each registered as arch/<id> and
 #: traced/<id>)
 ARCH_IDS = [
     "mamba2_780m", "dbrx_132b", "llama4_maverick_400b_a17b", "yi_6b",
     "tinyllama_1_1b", "mistral_nemo_12b", "stablelm_1_6b",
     "internvl2_2b", "recurrentgemma_2b", "whisper_small",
+    "mellum2_12b_a2_5b",
 ]
 
 

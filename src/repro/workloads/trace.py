@@ -13,7 +13,10 @@ jax primitive          Op lowering
 ``dot_general``        ``matmul`` with the true contraction dims
                        (m = batch x lhs-free, k = contracting, n =
                        rhs-free) and a precision resolved from the
-                       per-param-path width map
+                       per-param-path width map; a batched product
+                       whose rhs is a stack of expert weights
+                       (``expert_paths``) is one ``matmul`` per expert
+                       (m = lhs-free), marked ``expert``
 ``conv_general_dilated`` ``conv`` (n = output elements, k = taps x
                        C_in/groups, ``in_elems`` = input elements)
 ``gather`` / ``scatter`` / ``movement`` of the transferred elements at the
@@ -228,8 +231,9 @@ _EMPTY = _VarInfo()
 
 class _Tracer:
     def __init__(self, *, precision_map, default_width, sys, scan_mode,
-                 matmul_chunk, matmul_working_set):
+                 matmul_chunk, matmul_working_set, expert_paths=()):
         self.precision_map = dict(precision_map or {})
+        self.expert_paths = frozenset(expert_paths)
         self.default_width = default_width
         self.sys = sys
         self.scan_mode = scan_mode
@@ -249,7 +253,10 @@ class _Tracer:
         return self.env.get(atom, _EMPTY)
 
     def write(self, var, info: _VarInfo) -> None:
-        self.env[var] = info
+        from jax.extend.core import Literal
+
+        if not isinstance(var, Literal):  # a constant carry fed back
+            self.env[var] = info
 
     def _unique(self, base: str) -> str:
         n = self._name_counts.get(base, 0)
@@ -368,7 +375,10 @@ class _Tracer:
             rhs.shape[d] for d in range(rhs.ndim)
             if d not in rhs_c and d not in rhs_b) or 1
         k = math.prod(lhs.shape[d] for d in lhs_c) or 1
-        m = max(1, batch * lhs_free)
+        # a stack of expert weights: each expert is its own product
+        experts = batch if (lhs_b and infos[1].origins
+                            & self.expert_paths) else 0
+        m = max(1, lhs_free if experts else batch * lhs_free)
         n = max(1, rhs_free)
         widths = [self._operand_width(i, v.aval)
                   for i, v in zip(infos, eqn.invars)]
@@ -379,11 +389,12 @@ class _Tracer:
         base = leaves[0] if len(leaves) == 1 else "dot"
         ws = (self.matmul_working_set(width)
               if self.matmul_working_set else None)
-        op = Op(name=self._unique(base), kind="matmul", m=m, k=k, n=n,
-                width=width, chunk=min(self.matmul_chunk, k),
-                mixed_precision=(len(set(widths)) > 1),
-                working_set_bits=ws)
-        info = self.emit(op, infos)
+        emitted = [self.emit(Op(
+            name=self._unique(base), kind="matmul", m=m, k=k, n=n,
+            width=width, chunk=min(self.matmul_chunk, k),
+            mixed_precision=(len(set(widths)) > 1), working_set_bits=ws,
+            expert=bool(experts)), infos) for _ in range(experts or 1)]
+        info = _VarInfo.union(emitted)
         for v in eqn.outvars:
             self.write(v, info)
 
@@ -520,7 +531,8 @@ def trace_workload(fn: Callable, *example_args,
                    source: str = "traced", default_width: int = 16,
                    sys: SystemParams = PAPER_SYSTEM,
                    scan_mode: str = "once", matmul_chunk: int = 64,
-                   matmul_streamed_working_set: bool = True) -> Workload:
+                   matmul_streamed_working_set: bool = True,
+                   expert_paths: tuple[str, ...] = ()) -> Workload:
     """Trace ``fn(*example_args)`` into a :class:`Workload` DAG.
 
     ``example_args`` may be (pytrees of) ``jax.ShapeDtypeStruct`` --
@@ -537,6 +549,10 @@ def trace_workload(fn: Callable, *example_args,
     ``working_set_bits`` to the streamed-MAC live set (``8 * width``),
     the serving convention of ``registry.arch_workload``; pass False to
     keep the weight-stationary default of ``Op.features()``.
+
+    ``expert_paths``: flattened-argument key paths of stacked expert
+    weights (``[E, k, n]``); a batched product with one of them as its
+    rhs lowers to one ``expert`` matmul per stacked expert.
     """
     import jax
 
@@ -552,7 +568,7 @@ def trace_workload(fn: Callable, *example_args,
                     sys=sys, scan_mode=scan_mode, matmul_chunk=matmul_chunk,
                     matmul_working_set=(
                         (lambda w: w * 8) if matmul_streamed_working_set
-                        else None))
+                        else None), expert_paths=expert_paths)
         invar_infos = [
             _VarInfo(origins=frozenset({_format_path(path)}))
             for path, _leaf in paths]

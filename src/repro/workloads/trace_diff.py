@@ -11,11 +11,11 @@ from opposite ends.  This module pins them against each other, op by op:
   must agree to the cycle on every static backend
   (:data:`GATED_BACKENDS`);
 * ``divergent`` matches carry a documented reason (flash chunking,
-  capacity-grouped experts, all-head SSD contraction, ...) and their
+  per-expert capacity GEMMs, all-head SSD contraction, ...) and their
   deltas are recorded, never asserted;
 * every *remaining* traced op must be explained by a lowering rule
-  (:func:`_extra_note`) -- sibling projections, PV chunks, MoE
-  dispatch/combine, cache movement -- or the gate fails.
+  (:func:`_extra_note`) -- sibling projections, PV chunks, the other
+  experts' products, cache movement -- or the gate fails.
 
 :func:`run_diff` drives the full matrix and :func:`write_csv` emits the
 ``bench-artifacts/traced_vs_formula.csv`` artifact (per-op and TOTAL
@@ -99,16 +99,11 @@ def _flash_chunk(seq: int) -> int:
     return chunk
 
 
-def _moe_grouping(cfg, tokens: int) -> tuple[int, int, int]:
-    """(group_tokens, n_groups, capacity) as ``models.layers.moe_block``
-    computes them for ``tokens`` decode sequences (B*S = tokens)."""
-    t_grp = min(512, tokens)
-    while tokens % t_grp:
-        t_grp //= 2
-    groups = tokens // t_grp
-    cap = int(math.ceil(t_grp * cfg.top_k * cfg.capacity_factor
-                        / cfg.n_experts))
-    return t_grp, groups, cap
+def _capacity(cfg, tokens: int) -> int:
+    """Slots per expert, as ``models.layers.expert_capacity`` computes
+    them for ``tokens`` decode sequences."""
+    return int(math.ceil(tokens * cfg.top_k * cfg.capacity_factor
+                         / cfg.n_experts))
 
 
 def expected_matmuls(cfg, *, tokens: int = 4096,
@@ -146,15 +141,13 @@ def expected_matmuls(cfg, *, tokens: int = 4096,
                             (T, cfg.n_heads * cfg.head_dim, D, wb),
                             "exact"))
     if cfg.n_experts:
-        _t_grp, groups, cap = _moe_grouping(cfg, T)
+        cap = _capacity(cfg, T)
         out.append(Expected("router", "matmul",
                             (T, D, cfg.n_experts, 16), "exact"))
         out.append(Expected(
-            "expert_ffn", "matmul",
-            (cfg.n_experts * cfg.d_ff, D, groups * cap, wb), "divergent",
-            "formula scores a token-major top_k*T GEMM; the trace is the "
-            "capacity-grouped expert einsum (lhs = stacked expert "
-            f"weights, rhs = {groups} groups x capacity {cap})"))
+            "expert_ffn", "matmul", (cap, D, cfg.d_ff, wb), "divergent",
+            "formula scores a token-major top_k*T GEMM; the trace is one "
+            f"GEMM per held expert over its {cap} capacity slots"))
     elif cfg.d_ff:
         out.append(Expected("ffn", "matmul", (T, D, cfg.d_ff, wb),
                             "exact"))
@@ -200,7 +193,7 @@ def _extra_note(op: Op, cfg, tokens: int,
         return None
     T, D, wb = tokens, cfg.d_model, weight_bits
     fdims = {D, cfg.qkv_dim, cfg.n_heads * cfg.head_dim, cfg.d_ff,
-             cfg.padded_vocab, cfg.lru_width}
+             cfg.padded_vocab, cfg.lru_width, cfg.n_experts}
     if cfg.ssm_state:
         fdims |= {cfg.d_inner,
                   2 * cfg.d_inner + 2 * cfg.ssm_state + cfg.ssm_heads}
@@ -212,6 +205,8 @@ def _extra_note(op: Op, cfg, tokens: int,
     chunks = {_flash_chunk(T)}
     if cfg.enc_seq:  # cross-attention reads the encoder sequence
         chunks.add(_flash_chunk(cfg.enc_seq))
+    if cfg.window:  # local layers read a window-slot ring buffer
+        chunks.add(_flash_chunk(min(cfg.window, T)))
     if cfg.n_heads and cfg.n_kv_heads:
         group = cfg.n_heads // cfg.n_kv_heads
         for chunk in chunks:
@@ -225,23 +220,12 @@ def _extra_note(op: Op, cfg, tokens: int,
         if (op.width == 16 and op.k == 1 and op.n == cfg.ssm_state
                 and op.m == T * cfg.d_inner):
             return "SSD state outer-product update (rank-1 per channel)"
-    if cfg.n_experts:
-        t_grp, groups, cap = _moe_grouping(cfg, T)
-        e, f = cfg.n_experts, cfg.d_ff
-        if op.width == wb and (op.m, op.k, op.n) == (e * f, D,
-                                                     groups * cap):
-            return "stacked expert up/gate projection (expert_ffn sibling)"
-        if op.width == wb and (op.m, op.k, op.n) == (e * D, f,
-                                                     groups * cap):
-            return "stacked expert down projection"
-        if op.width == 16 and (op.m, op.k, op.n) == (e * groups * cap,
-                                                     t_grp, D):
-            return "MoE capacity dispatch (one-hot gather matmul)"
-        if op.width == 16 and (op.m, op.k, op.n) == (T, e * cap, D):
-            return "MoE capacity combine (weighted scatter matmul)"
-        bound = groups * t_grp * e * max(cfg.top_k, 1) * cap
-        if op.width == 16 and op.m * op.k * op.n <= bound:
-            return "MoE routing bookkeeping (top-k/one-hot select dots)"
+    if cfg.n_experts and op.expert and op.width == wb:
+        cap = _capacity(cfg, T)
+        if (op.m, op.k, op.n) == (cap, D, cfg.d_ff):
+            return "expert up/gate projection (expert_ffn sibling)"
+        if (op.m, op.k, op.n) == (cap, cfg.d_ff, D):
+            return "expert down projection"
     return None
 
 
@@ -429,7 +413,7 @@ def run_diff(archs: Optional[Sequence[str]] = None, *,
              pallas_archs: Sequence[str] = (), include_vgg: bool = True,
              sys: SystemParams = PAPER_SYSTEM
              ) -> tuple[list[OpRow], list[str]]:
-    """Reconcile ``archs`` (default: all 10) + VGG; -> (rows, failures)."""
+    """Reconcile ``archs`` (default: every arch) + VGG; -> (rows, failures)."""
     rows: list[OpRow] = []
     for arch in archs or ARCH_IDS:
         bks = tuple(backends)

@@ -252,7 +252,7 @@ def test_arch_workload_and_advisor_shim():
 
 def test_arch_workloads_registered():
     names = workload_names("arch")
-    assert "arch/tinyllama_1_1b" in names and len(names) == 10
+    assert "arch/tinyllama_1_1b" in names and len(names) == 11
     w = get_workload("arch/tinyllama_1_1b")
     assert all(op.kind == "matmul" for op in w.ops)
 
