@@ -26,7 +26,19 @@ that execution model (DESIGN.md Sec. 15):
   already passed device arrays) and read in place by every ``run()``,
   which fetches fresh results each call.  Nothing is donated: XLA
   aliases an input only into an output of the same shape and dtype, and
-  entries are int8 ``[m, k]`` while every output is int32 ``[m, n]``.
+  entries are int8 ``[m, k]`` while the output is int32.
+* Results leave the device in a few flat buffers: the program writes
+  each step's int32 ``[m, n]`` result, as soon as the step is done, into
+  a flat 1-D int32 buffer at a static offset (steps in order, no gaps;
+  a new buffer starts where the next result would pass
+  :data:`FETCH_CHUNK_BYTES`), and ``run()`` starts every buffer's copy
+  before it waits on the first, then hands back each step's result as a
+  view of its buffer.  One copy per step paid a host-side set-up and a
+  layout conversion per array, in series; one copy of everything runs
+  at a single copy's speed, which falls once a buffer passes a few tens
+  of MB.  Writing in place (not one concatenate at the end) lets each
+  step's padded kernel output die with its step, so the device holds no
+  more than the result bytes.
 
 Per-step ``run_schedule`` stays authoritative as the differential
 reference: with the same threading it is bit-exact with the chained
@@ -51,6 +63,13 @@ import numpy as np
 from repro import spans
 from repro.core.cost_model import Layout
 from repro.plan.pallas import MAX_BS_WIDTH, PallasSchedule, synth_inputs
+
+#: results leave the device in flat int32 buffers of at most this many
+#: bytes (a step's result is never split), every copy in flight at once:
+#: on a TPU v5e host one copy of a ~26 MB result ran at ~8 GB/s, one of
+#: ~100 MB or more at 2.3-3.4 GB/s, and overlapped copies of the same
+#: bytes at ~11 GB/s (PERF.md, section 6)
+FETCH_CHUNK_BYTES = 16 << 20
 
 #: default :class:`ExecutableCache` capacity -- live executables are far
 #: heavier than cached plans (jitted closures + device-resident params),
@@ -116,6 +135,9 @@ class ScheduleExecutable:
     _fn: Any = dataclasses.field(repr=False)
     _params: Any = dataclasses.field(repr=False)
     _entry: dict = dataclasses.field(repr=False)   #: resident entries
+    #: {op: (buffer, offset, m, n)}: where each result sits in the
+    #: program's flat output buffers
+    _slots: dict = dataclasses.field(repr=False)
     runs: int = 0
 
     def run(self) -> dict:
@@ -123,12 +145,15 @@ class ScheduleExecutable:
         {op: int32 [m, n] numpy result} for every measured step.
 
         Entry activations are resident on the device and read in place:
-        nothing is donated, so running twice is safe and bit-identical,
-        and each call's results are fresh arrays.  Each call records the
-        spans ``schedule.run`` > ``schedule.place`` / ``.dispatch`` /
-        ``.wait`` / ``.fetch`` and the counters ``schedule.place_bytes``
-        (entry bytes moved host->device: 0 when every entry is resident)
-        / ``schedule.fetch_bytes`` (``repro.spans``).
+        nothing is donated, so running twice is safe and bit-identical.
+        The results are views of the program's flat output buffers,
+        fetched with every copy in flight at once; each call's buffers
+        are fresh.  Each call records the spans ``schedule.run`` >
+        ``schedule.place`` / ``.dispatch`` / ``.wait`` / ``.fetch`` and
+        the counters ``schedule.place_bytes`` (entry bytes moved
+        host->device: 0 when every entry is resident) /
+        ``schedule.fetch_arrays`` (device arrays fetched) /
+        ``schedule.fetch_bytes`` (``repro.spans``).
         """
         import jax
 
@@ -142,9 +167,11 @@ class ScheduleExecutable:
             with spans.span("schedule.wait"):
                 jax.block_until_ready(out)
             with spans.span("schedule.fetch"):
-                got = {op: np.asarray(y) for op, y in out.items()}
-            spans.count("schedule.fetch_bytes",
-                        sum(v.nbytes for v in got.values()))
+                host = jax.device_get(out)   # starts every copy first
+                got = {op: host[b][o:o + m * n].reshape(m, n)
+                       for op, (b, o, m, n) in self._slots.items()}
+            spans.count("schedule.fetch_arrays", len(host))
+            spans.count("schedule.fetch_bytes", sum(h.nbytes for h in host))
             self.runs += 1
         return got
 
@@ -223,8 +250,19 @@ def compile_schedule(schedule: PallasSchedule,
             x = jnp.pad(x, ((0, 0), (0, k_planes - x.shape[1])))
         return bitserial_matmul(x, planes)
 
+    # each result's place in the flat outputs, in step order
+    slots: dict[str, tuple[int, int, int, int]] = {}
+    sizes: list[int] = []      # elements of each flat output
+    for s in steps:
+        m, _k, n = s.dims
+        if not sizes or (sizes[-1] + m * n) * 4 > FETCH_CHUNK_BYTES:
+            sizes.append(0)
+        slots[s.op] = (len(sizes) - 1, sizes[-1], m, n)
+        sizes[-1] += m * n
+
     def program(xs, ps):
         out = {}
+        bufs = [jnp.zeros((size,), jnp.int32) for size in sizes]
         for s in steps:
             m, k, _n = s.dims
             src = producer.get(s.op)
@@ -244,7 +282,12 @@ def compile_schedule(schedule: PallasSchedule,
                 else:
                     y = _bs(x, w)
             out[s.op] = y
-        return out
+            # in place, as soon as the step is done: its padded kernel
+            # output is not kept alive to the end of the program
+            b, o, _m, _n = slots[s.op]
+            bufs[b] = jax.lax.dynamic_update_slice(
+                bufs[b], y.reshape(-1), (o,))
+        return tuple(bufs)
 
     fn = jax.jit(program)
     # build = trace + lower + compile + first (warming) run, on the same
@@ -262,7 +305,7 @@ def compile_schedule(schedule: PallasSchedule,
         params_bytes=sum(int(np.prod(p.shape)) * p.dtype.itemsize
                          for p in params.values()),
         entry_bytes=sum(v.nbytes for v in entry.values()),
-        _fn=fn, _params=params, _entry=entry)
+        _fn=fn, _params=params, _entry=entry, _slots=slots)
 
 
 class ExecutableCache:

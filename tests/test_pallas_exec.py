@@ -131,6 +131,93 @@ def test_each_run_returns_fresh_result_arrays():
         assert not np.shares_memory(a[op], b[op]), op
 
 
+def _chain_schedule():
+    """A linear chain of three matmuls of different ``[m, n]``: every
+    step after the first is threaded from its producer's result."""
+    w = Workload(name="chain", ops=(
+        Op(name="a", kind="matmul", m=4, k=64, n=48, width=8),
+        Op(name="b", kind="matmul", m=8, k=40, n=16, width=16),
+        Op(name="c", kind="matmul", m=2, k=96, n=24, width=4)))
+    sched = lower_plan_pallas(compile_plan(w), w)
+    assert sched.threaded_producers() == {"b": "a", "c": "b"}
+    return sched
+
+
+def test_chained_matches_per_step_and_reference_on_threaded_chain():
+    sched = _chain_schedule()
+    inputs = synth_inputs(sched, seed=8)
+    got = compile_schedule(sched, inputs, seed=8).run()
+    per = run_schedule(sched, inputs)
+    ref = reference_results(sched, inputs)
+    assert {op: v.shape for op, v in got.items()} == {
+        "a": (4, 48), "b": (8, 16), "c": (2, 24)}
+    for op in got:
+        assert got[op].dtype == np.int32, op
+        np.testing.assert_array_equal(got[op], per[op], err_msg=op)
+        np.testing.assert_array_equal(got[op], ref[op], err_msg=op)
+
+
+def _build(build):
+    return (_hybrid_schedule("vgg13")[1] if build == "hybrid"
+            else _chain_schedule())
+
+
+#: (schedule, FETCH_CHUNK_BYTES) -> the elements of each flat output:
+#: the chain's results are 192, 128 and 48 elements, vgg13's 512, 512
+#: and 10; a new buffer starts where the next result would pass the
+#: chunk, and a result larger than the chunk sits alone
+BUFFERS = [("hybrid", None, [1034]), ("chain", None, [368]),
+           ("chain", 1024, [192, 176]), ("chain", 4, [192, 128, 48]),
+           ("hybrid", 2048, [512, 512, 10]), ("hybrid", 2088, [512, 522])]
+
+
+@pytest.mark.parametrize("build,chunk,sizes", BUFFERS)
+def test_program_returns_flat_int32_buffers(monkeypatch, build, chunk,
+                                            sizes):
+    """The program's outputs are flat int32 buffers that hold every
+    step's result, in step order and without gaps."""
+    from repro.plan import pallas_exec
+
+    if chunk is not None:
+        monkeypatch.setattr(pallas_exec, "FETCH_CHUNK_BYTES", chunk)
+    sched = _build(build)
+    exe = compile_schedule(sched, synth_inputs(sched, seed=9), seed=9)
+    bufs = exe._fn(exe._entry, exe._params)
+    assert [b.shape for b in bufs] == [(n,) for n in sizes]
+    assert all(b.dtype == np.int32 for b in bufs)
+    got = exe.run()
+    assert spans.last("schedule.fetch_arrays") == len(sizes)
+    assert spans.last("schedule.fetch_bytes") == 4 * sum(sizes) == sum(
+        v.nbytes for v in got.values())
+    np.testing.assert_array_equal(
+        np.concatenate([got[s.op].reshape(-1)
+                        for s in sched.measured_steps]),
+        np.concatenate([np.asarray(b) for b in bufs]))
+    ref = reference_results(sched, synth_inputs(sched, seed=9))
+    for op in got:
+        np.testing.assert_array_equal(got[op], ref[op], err_msg=op)
+
+
+@pytest.mark.parametrize("build", ["hybrid", "chain"])
+def test_one_call_results_are_views_of_one_buffer(build):
+    """A small schedule's results fit one buffer: one call's results
+    are views of it, back to back, and the next call's are not."""
+    sched = _build(build)
+    exe = compile_schedule(sched, synth_inputs(sched, seed=10), seed=10)
+    a, b = exe.run(), exe.run()
+    ops = [s.op for s in sched.measured_steps]
+    base = a[ops[0]].base
+    assert base is not None
+    for op in ops:
+        assert a[op].base is base, op
+        assert np.shares_memory(a[op], base), op
+        assert not np.shares_memory(a[op], b[op].base), op
+    # back to back, in step order
+    addr = {op: a[op].__array_interface__["data"][0] for op in ops}
+    for p, q in zip(ops, ops[1:]):
+        assert addr[p] + a[p].nbytes == addr[q], (p, q)
+
+
 def test_summary_reports_resident_entry_bytes():
     _, sched = _hybrid_schedule("vgg13")
     inputs = synth_inputs(sched, seed=7)

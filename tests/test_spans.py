@@ -177,6 +177,16 @@ def test_transfer_counters_are_the_entry_and_result_bytes(exe):
     assert spans.last("schedule.fetch_bytes") > 0
 
 
+def test_a_small_schedule_fetches_one_array(exe):
+    # vgg13's three classifier results fit one FETCH_CHUNK_BYTES buffer
+    exe.run()
+    got = exe.run()
+    assert spans.last("schedule.fetch_arrays") == 1
+    assert spans.recent("schedule.fetch_arrays", 2) == [1, 1]
+    assert spans.last("schedule.fetch_bytes") == sum(
+        v.nbytes for v in got.values())
+
+
 def test_warm_run_compiles_nothing(exe):
     exe.run()
     exe.run()
