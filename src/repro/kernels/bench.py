@@ -7,9 +7,12 @@ GEMV 1x4096x512, the VGG classifier FCs) at weight widths {1, 4, 8, 16}
 
 * ``bp``          -- the grid-tiled bit-parallel word kernel,
 * ``bs_fused``    -- the one-kernel fused bitpack-matmul,
-* ``bs_unfused``  -- ``pack_weights`` -> ``matmul_bs`` with the pack pass
+* ``bs_unfused``  -- bitpack -> the bitplane kernel with the pack pass
   *on* the timed path (the materialized-plane-artifact cost fusion
   removes; the fused-vs-unfused delta is the point of the comparison).
+
+Each path is a lowered step (``plan.pallas.step_for``) run through the
+schedule's own step function (``hold`` / ``apply``).
 
 With ``chained=True`` the run also times whole-schedule execution for
 the multi-step Table-6 apps (:data:`CHAINED_APPS`): the per-step host
@@ -33,8 +36,7 @@ the gate protects.
 """
 from __future__ import annotations
 
-import statistics
-import time
+import sys
 from typing import Optional
 
 import numpy as np
@@ -57,18 +59,6 @@ QUICK_WIDTHS: tuple[int, ...] = (4, 8, 16)
 CHAINED_APPS: tuple[str, ...] = ("vgg13", "vgg16", "vgg19", "gemv")
 
 
-def _clock(fn, reps: int) -> float:
-    import jax
-
-    jax.block_until_ready(fn())  # warmup / compile
-    samples = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        samples.append((time.perf_counter() - t0) * 1e6)
-    return statistics.median(samples)
-
-
 def run_chained_bench(*, apps=CHAINED_APPS, reps: int = 5, seed: int = 0,
                       max_macs: Optional[int] = None
                       ) -> tuple[list[dict], dict]:
@@ -84,6 +74,7 @@ def run_chained_bench(*, apps=CHAINED_APPS, reps: int = 5, seed: int = 0,
     """
     from repro.plan import (compile_plan, compile_schedule,
                             lower_plan_pallas, run_schedule, synth_inputs)
+    from repro.plan.pallas import clock
     from repro.workloads import get_workload
 
     cases: list[dict] = []
@@ -97,9 +88,9 @@ def run_chained_bench(*, apps=CHAINED_APPS, reps: int = 5, seed: int = 0,
             meta[app] = {"skipped": "no measured steps under budget"}
             continue
         inputs = synth_inputs(sched, seed=seed)
-        per_us = _clock(lambda: run_schedule(sched, inputs), reps)
+        per_us = clock(lambda: run_schedule(sched, inputs), reps)
         exe = compile_schedule(sched, inputs)
-        chained_us = _clock(exe.run, reps)
+        chained_us = clock(exe.run, reps)
         per = run_schedule(sched, inputs)
         got = exe.run()
         for op, y in got.items():
@@ -123,13 +114,21 @@ def run_pallas_bench(*, quick: bool = False, reps: Optional[int] = None,
                      seed: int = 0,
                      shapes=None, widths=None, chained: bool = False,
                      chained_apps=None) -> dict:
-    """Time every case; returns the BENCH_pallas.json payload dict."""
+    """Time every case; returns the BENCH_pallas.json payload dict.
+
+    Each path is the step the schedule lowering makes of the shape
+    (``plan.pallas.step_for``), timed as ``plan.pallas.apply`` on weights
+    ``plan.pallas.hold`` made outside the timed call: ``bp`` a BP step,
+    ``bs_fused`` and ``bs_unfused`` a ``bp2bs`` boundary into BS, fused or
+    not -- so the unfused pack stays on the timed path.
+    """
     import jax.numpy as jnp
 
-    from repro.kernels import ops as kops
+    from repro.core.cost_model import Layout
     from repro.kernels import platform
-    from repro.kernels import tiling as tl
+    from repro.plan.pallas import apply_jit, clock, hold, step_for
     from repro.util import rand_words
+    from repro.workloads.ir import Op
 
     if shapes is None:
         shapes = BENCH_SHAPES
@@ -144,25 +143,20 @@ def run_pallas_bench(*, quick: bool = False, reps: Optional[int] = None,
                         ).astype(jnp.int8)
         for bits in widths:
             w = jnp.asarray(rand_words(rng, bits, (k, n)))
-            limbs = kops.bp_limbs(w, bits)
-
-            def bs_unfused(w=w, x=x, bits=bits):
-                return kops.matmul_bs(x, kops.pack_weights(w, bits))
-
-            paths = (
-                ("bp", tl.bp_tiling(m, k, n),
-                 lambda x=x, limbs=limbs: kops.matmul_bp(x, limbs)),
-                ("bs_fused", tl.fused_tiling(m, k, n),
-                 lambda x=x, w=w, bits=bits: kops.matmul_bs_fused(
-                     x, w, bits)),
-                ("bs_unfused", tl.bs_tiling(m, k, n), bs_unfused),
-            )
-            for path, tiling, fn in paths:
+            op = Op(name=shape_name, kind="matmul", m=m, k=k, n=n,
+                    width=bits)
+            for path, layout, repack, fused in (
+                    ("bp", Layout.BP, None, False),
+                    ("bs_fused", Layout.BS, "bp2bs", True),
+                    ("bs_unfused", Layout.BS, "bp2bs", False)):
+                step = step_for(op, layout, repack, fuse_pack=fused,
+                                max_macs=sys.maxsize)
+                held = hold(step, w)
                 cases.append({
                     "name": f"{shape_name}/w{bits}/{path}",
                     "shape": [m, k, n], "width": bits, "path": path,
-                    "padded": list(tiling.padded_dims),
-                    "us": _clock(fn, reps),
+                    "padded": list(step.padded_dims),
+                    "us": clock(lambda: apply_jit(step, x, held), reps),
                 })
     payload = {"reps": reps, "quick": quick,
                "interpret": platform.interpret(),

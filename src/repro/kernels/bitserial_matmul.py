@@ -64,7 +64,9 @@ def _kernel(x_ref, planes_ref, o_ref, acc_ref, *, bits: int, bk: int,
 def bitserial_matmul(x: jax.Array, planes: jax.Array, *,
                      block_m: int = 128, block_n: int = 128,
                      block_k: int = 512) -> jax.Array:
-    """x: int8 [M, K]; planes: uint32 [bits, K//32, N] -> int32 [M, N]."""
+    """x: int8 [M, K]; planes: uint32 [bits, ceil(K/32), N] -> int32
+    [M, N].  A K short of whole 32-row groups (``bitpack``'s zero-padded
+    tail) is padded here."""
     if x.dtype != jnp.int8:
         raise TypeError(f"MXU activations must be int8, got {x.dtype}")
     M, K = x.shape

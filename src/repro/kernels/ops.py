@@ -5,6 +5,7 @@ first-class kernel-selection decision).
 from __future__ import annotations
 
 import functools
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -56,39 +57,24 @@ def unpack_weights(planes: jax.Array, k: int | None = None):
     return bitunpack(planes, k)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "block_m", "block_n", "block_k"))
-def matmul_bs(x: jax.Array, planes: jax.Array,
-              block_m: int = 128, block_n: int = 128, block_k: int = 512):
-    # bitpack zero-pads K to a multiple of 32; mirror the padding on the
-    # activation side (zero rows contribute nothing to the contraction)
-    k_planes = planes.shape[1] * 32
-    if x.shape[1] != k_planes:
-        x = jnp.pad(x, ((0, 0), (0, k_planes - x.shape[1])))
-    return bitserial_matmul(x, planes,
-                            block_m=block_m, block_n=block_n,
-                            block_k=max(block_k, 256))
+@jax.jit
+def matmul_bs(x: jax.Array, planes: jax.Array):
+    """BS bitplane matmul over packed planes (:func:`pack_weights`)."""
+    return bitserial_matmul(x, planes)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "block_m", "block_n", "block_k"))
-def matmul_bp(x: jax.Array, limbs: jax.Array,
-              block_m: int = 128, block_n: int = 128, block_k: int = 128):
+@jax.jit
+def matmul_bp(x: jax.Array, limbs: jax.Array):
     """BP word matmul over resident int8 limbs (:func:`bp_limbs`)."""
-    return bitparallel_matmul(x, limbs, block_m=block_m,
-                              block_n=block_n, block_k=block_k)
+    return bitparallel_matmul(x, limbs)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "bits", "block_m", "block_n", "block_k"))
-def matmul_bs_fused(x: jax.Array, w: jax.Array, bits: int,
-                    block_m: int = 128,
-                    block_n: int = 128, block_k: int = 128):
+@functools.partial(jax.jit, static_argnames=("bits",))
+def matmul_bs_fused(x: jax.Array, w: jax.Array, bits: int):
     """One-kernel BS path: packs plane slices in VMEM and accumulates the
     plane loop without materializing the ``[bits, K/32, N]`` artifact.
     Bit-exact with ``pack_weights`` -> ``matmul_bs``."""
-    return fused_bitserial_matmul(x, w, bits, block_m=block_m, block_n=block_n,
-                                  block_k=block_k)
+    return fused_bitserial_matmul(x, w, bits)
 
 
 def choose_layout(*, weight_bits: int, m: int, n: int, k: int,
@@ -118,9 +104,13 @@ def planned_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int,
     compiled :class:`repro.plan.ir.LayoutPlan` -- the same plan the cost
     model priced.  ``plan.layout_for(op_name)`` picks the kernel; with no
     plan, fall back to the Table-8 advisor (:func:`choose_layout`).
-    ``fuse_pack=True`` folds the BP->BS repack into the BS kernel itself
-    (no materialized plane tensor).  w: unsigned ints < 2^weight_bits,
-    [K, N].  Returns (y, Layout)."""
+    The product runs as the lowered step of a schedule does
+    (``plan.pallas.step_for`` / ``hold`` / ``apply``): BS weights arrive
+    in word form (a ``bp2bs`` step), and ``fuse_pack=True`` folds that
+    repack into the BS kernel itself (no materialized plane tensor).
+    w: unsigned ints < 2^weight_bits, [K, N].  Returns (y, Layout)."""
+    from repro.plan.pallas import apply, hold, step_for
+
     m, k = x.shape
     n = w.shape[1]
     if plan is not None:
@@ -128,14 +118,8 @@ def planned_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int,
     else:
         rec = choose_layout(weight_bits=weight_bits, m=m, n=n, k=k)
         layout = Layout.BS if rec == Recommendation.BS else Layout.BP
-    if layout is Layout.BS:
-        if fuse_pack:
-            return matmul_bs_fused(x, w, weight_bits), Layout.BS
-        return matmul_bs(x, pack_weights(w, weight_bits)), Layout.BS
-    return matmul_bp(x, bp_limbs(w, weight_bits)), Layout.BP
-
-
-def layout_aware_matmul(x: jax.Array, w: jax.Array, *, weight_bits: int):
-    """Advisor-driven dispatch (no plan): x @ w via the BS or BP kernel
-    per the Table-8 verdict. w: unsigned ints < 2^weight_bits, [K, N]."""
-    return planned_matmul(x, w, weight_bits=weight_bits)
+    op = Op(name=op_name or "matmul", kind="matmul", m=m, k=k, n=n,
+            width=weight_bits)
+    step = step_for(op, layout, "bp2bs" if layout is Layout.BS else None,
+                    fuse_pack=fuse_pack, max_macs=sys.maxsize)
+    return apply(step, x, hold(step, w)), layout

@@ -34,6 +34,12 @@ Ops whose *padded* MAC volume (times MXU passes: planes for BS, limbs
 for BP -- :func:`mxu_passes`) exceeds ``max_macs`` are lowered as
 modelled-only too -- an honest "too large to time here" note, never a
 silently clamped measurement.
+
+Every path that runs a lowered step -- the compiled program
+(``plan.pallas_exec``), :func:`run_schedule`, :func:`time_schedule`,
+``PallasBackend``, ``pallas-bench``, ``kernels.ops.planned_matmul`` --
+makes it with :func:`step_for` and runs ``apply(step, x, hold(step, w))``;
+no other code above the kernels calls them.
 """
 from __future__ import annotations
 
@@ -43,6 +49,7 @@ import statistics
 import time
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro import spans
@@ -169,6 +176,106 @@ def _tiling(layout: Layout, fused: bool, m: int, k: int, n: int):
     return tl.fused_tiling(m, k, n) if fused else tl.bs_tiling(m, k, n)
 
 
+def step_for(op, layout: Layout, repack: Optional[str], *,
+             fuse_pack: bool = True,
+             max_macs: int = DEFAULT_MAX_MACS) -> PallasStep:
+    """The step ``op`` lowers to in ``layout`` behind ``repack``: its
+    dims, the width limit, the tiling, the MAC budget and the kernel
+    choice, or the modelled-only row that says why there is none."""
+    if op.kind not in _MEASURABLE:
+        return PallasStep(
+            op=op.name, kind=op.kind, layout=layout, width=op.width,
+            kernel=None, repack=repack,
+            note="modelled only: no Pallas lowering for "
+                 f"{op.kind!r} ops (DESIGN.md Sec. 14)")
+    m, k, n = _op_dims(op)
+    if layout is Layout.BS and op.width > MAX_BS_WIDTH:
+        return PallasStep(
+            op=op.name, kind=op.kind, layout=layout, width=op.width,
+            kernel=None, repack=repack, dims=(m, k, n),
+            note=f"unsupported: width {op.width} > {MAX_BS_WIDTH} "
+                 "plane passes (uint32 plane words)")
+    fused = fuse_pack and layout is Layout.BS and repack == "bp2bs"
+    t = _tiling(layout, fused, m, k, n)
+    passes = mxu_passes(layout, op.width)
+    if t.padded_macs * passes > max_macs:
+        return PallasStep(
+            op=op.name, kind=op.kind, layout=layout, width=op.width,
+            kernel=None, repack=repack, dims=(m, k, n),
+            padded_dims=t.padded_dims,
+            note=f"over budget: {t.padded_macs * passes} padded MACs "
+                 f"> max_macs={max_macs} -- not timed")
+    if layout is Layout.BP:
+        kernel = "bitparallel_matmul"
+    elif fused:
+        kernel = "fused_bitserial_matmul"
+    else:
+        kernel = "bitserial_matmul"
+    return PallasStep(
+        op=op.name, kind=op.kind, layout=layout, width=op.width,
+        kernel=kernel, repack=repack, dims=(m, k, n),
+        padded_dims=t.padded_dims,
+        note="repack folded into fused kernel" if fused else "",
+        expert=op.expert)
+
+
+def _bs2bp_in_program(step: PallasStep) -> bool:
+    """A BP step whose weights arrive plane-resident: the plan-charged
+    unpack runs in the program, not at hold time."""
+    return step.repack == "bs2bp" and step.width <= MAX_BS_WIDTH
+
+
+def hold(step: PallasStep, w):
+    """Word-form weights ``[k, n]`` -> the device form ``step`` keeps
+    resident: int8 limbs for BP, packed planes for BS and for a
+    ``bs2bp`` step (the operand arrives plane-resident), the words
+    themselves for a ``bp2bs`` step (the pack runs in :func:`apply`)."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+
+    w = jnp.asarray(w)
+    if step.layout is Layout.BP and not _bs2bp_in_program(step):
+        return kops.bp_limbs(w, step.width)
+    if step.layout is Layout.BS and step.repack == "bp2bs":
+        return w
+    return kops.pack_weights(w, step.width)
+
+
+def apply(step: PallasStep, x, held):
+    """int8 activations ``[m, k]`` against :func:`hold`'s weights ->
+    int32 ``[m, n]``: the one kernel call of a lowered step.
+
+    A ``bs2bp`` step unpacks its planes and splits them into limbs
+    before the BP kernel; a fused ``bp2bs`` step runs the fused
+    bitpack-matmul; an unfused one packs in flight.  Pure jnp over the
+    raw kernels, so it traces into the compiled schedule program
+    unchanged; one step on its own runs as :data:`apply_jit`.
+    """
+    from repro.kernels.bitpack import bitpack, bitunpack
+    from repro.kernels.bitparallel_matmul import (bitparallel_matmul,
+                                                  split_limbs)
+    from repro.kernels.bitserial_matmul import bitserial_matmul
+    from repro.kernels.fused_bitserial_matmul import fused_bitserial_matmul
+
+    if not step.measured:
+        raise ValueError(f"{step.op}: no kernel to run ({step.note})")
+    if step.layout is Layout.BP:
+        if _bs2bp_in_program(step):
+            held = split_limbs(bitunpack(held, step.dims[1]), step.width)
+        return bitparallel_matmul(x, held)
+    if step.kernel == "fused_bitserial_matmul":
+        return fused_bitserial_matmul(x, held, step.width)
+    if step.repack == "bp2bs":
+        held = bitpack(held, step.width)
+    return bitserial_matmul(x, held)
+
+
+#: :func:`apply` as a program of its own, the step static: how one step
+#: runs outside the compiled schedule (per-step mode, per-step timings)
+apply_jit = jax.jit(apply, static_argnums=0)
+
+
 @spans.span("plan.lower")
 def lower_plan_pallas(plan: LayoutPlan, workload, *,
                       fuse_pack: bool = True,
@@ -182,44 +289,8 @@ def lower_plan_pallas(plan: LayoutPlan, workload, *,
         if current is not None and layout is not current:
             repack = "bp2bs" if layout is Layout.BS else "bs2bp"
         current = layout
-        if op.kind not in _MEASURABLE:
-            steps.append(PallasStep(
-                op=op.name, kind=op.kind, layout=layout, width=op.width,
-                kernel=None, repack=repack,
-                note="modelled only: no Pallas lowering for "
-                     f"{op.kind!r} ops (DESIGN.md Sec. 14)"))
-            continue
-        m, k, n = _op_dims(op)
-        if layout is Layout.BS and op.width > MAX_BS_WIDTH:
-            steps.append(PallasStep(
-                op=op.name, kind=op.kind, layout=layout, width=op.width,
-                kernel=None, repack=repack, dims=(m, k, n),
-                note=f"unsupported: width {op.width} > {MAX_BS_WIDTH} "
-                     "plane passes (uint32 plane words)"))
-            continue
-        fused = fuse_pack and layout is Layout.BS and repack == "bp2bs"
-        t = _tiling(layout, fused, m, k, n)
-        passes = mxu_passes(layout, op.width)
-        if t.padded_macs * passes > max_macs:
-            steps.append(PallasStep(
-                op=op.name, kind=op.kind, layout=layout, width=op.width,
-                kernel=None, repack=repack, dims=(m, k, n),
-                padded_dims=t.padded_dims,
-                note=f"over budget: {t.padded_macs * passes} padded MACs "
-                     f"> max_macs={max_macs} -- not timed"))
-            continue
-        if layout is Layout.BP:
-            kernel = "bitparallel_matmul"
-        elif fused:
-            kernel = "fused_bitserial_matmul"
-        else:
-            kernel = "bitserial_matmul"
-        steps.append(PallasStep(
-            op=op.name, kind=op.kind, layout=layout, width=op.width,
-            kernel=kernel, repack=repack, dims=(m, k, n),
-            padded_dims=t.padded_dims,
-            note="repack folded into fused kernel" if fused else "",
-            expert=op.expert))
+        steps.append(step_for(op, layout, repack, fuse_pack=fuse_pack,
+                              max_macs=max_macs))
     _count_expert_work(steps)
     return PallasSchedule(workload=workload.name, steps=tuple(steps),
                           fuse_pack=fuse_pack,
@@ -277,16 +348,17 @@ def run_schedule(schedule: PallasSchedule, inputs: dict, *,
     {op: int32 [m, n] result}.
 
     ``inputs`` maps op name -> (x, w) with w in word form (see
-    :func:`synth_inputs`).  BS steps pack (or fuse the pack of) their
-    weights per the schedule; BP steps run the word kernel losslessly.
+    :func:`synth_inputs`).  Each step runs ``apply(s, x, hold(s, w))``,
+    the step the compiled program (``plan.pallas_exec``) runs, repacks
+    included, but one launch and one host round-trip at a time.
 
     ``thread=True`` (default) feeds each step's activation from its
     nearest measured producer along ``schedule.edges()`` via
     ``kernels.ops.thread_activations`` -- the same dataflow the chained
-    executor (``plan.pallas_exec``) compiles, making per-step mode its
-    bit-exact differential reference (DESIGN.md Sec. 15).
-    ``thread=False`` runs every step on its own synthetic operands (the
-    per-kernel differential mode the executor-vs-simulator tests use).
+    executor compiles, making per-step mode its bit-exact differential
+    reference (DESIGN.md Sec. 15).  ``thread=False`` runs every step on
+    its own synthetic operands (the per-kernel differential mode the
+    executor-vs-simulator tests use).
     """
     import jax.numpy as jnp
 
@@ -300,16 +372,7 @@ def run_schedule(schedule: PallasSchedule, inputs: dict, *,
         if src in results:
             m, k, _ = s.dims
             x = kops.thread_activations(jnp.asarray(results[src]), m, k)
-        else:
-            x = jnp.asarray(x)
-        w = jnp.asarray(w)
-        if s.layout is Layout.BP:
-            y = kops.matmul_bp(x, kops.bp_limbs(w, s.width))
-        elif s.kernel == "fused_bitserial_matmul":
-            y = kops.matmul_bs_fused(x, w, s.width)
-        else:
-            y = kops.matmul_bs(x, kops.pack_weights(w, s.width))
-        results[s.op] = np.asarray(y)
+        results[s.op] = np.asarray(apply_jit(s, jnp.asarray(x), hold(s, w)))
     return results
 
 
@@ -338,6 +401,9 @@ def time_schedule(schedule: PallasSchedule, inputs: dict, *,
     Returns one record per schedule step: ``{op, kind, layout, kernel,
     repack, dims, padded_dims, width, us, note}`` -- ``us`` is None for
     modelled-only rows.  One warmup launch per step amortizes tracing.
+    The timed call is :func:`apply` on weights :func:`hold` made before
+    it: the per-step work of the compiled program, in-program repacks
+    included.
 
     Timing is memoized by ``(padded_dims, width, kernel)`` within one
     call: a repeated layer (VGG-style fc0/fc1 at identical shape) would
@@ -345,10 +411,7 @@ def time_schedule(schedule: PallasSchedule, inputs: dict, *,
     that is shape-determined anyway.  Memoized rows carry a note naming
     the step they reuse.
     """
-    import jax
     import jax.numpy as jnp
-
-    from repro.kernels import ops as kops
 
     rows = []
     memo: dict[tuple, tuple[float, str]] = {}
@@ -369,29 +432,21 @@ def time_schedule(schedule: PallasSchedule, inputs: dict, *,
                 rows.append(rec)
                 continue
             x, w = inputs[s.op]
-            x = jnp.asarray(x)
-            w = jnp.asarray(w)
-
-            if s.layout is Layout.BP:
-                limbs = kops.bp_limbs(w, s.width)
-
-                def fn(x=x, limbs=limbs):
-                    return kops.matmul_bp(x, limbs)
-            elif s.kernel == "fused_bitserial_matmul":
-                def fn(x=x, w=w, bits=s.width):
-                    return kops.matmul_bs_fused(x, w, bits)
-            else:
-                # unfused: the pack pass is part of the measured path --
-                # that is exactly the artifact fusion removes
-                def fn(x=x, w=w, bits=s.width):
-                    return kops.matmul_bs(x, kops.pack_weights(w, bits))
-            jax.block_until_ready(fn())  # warmup: trace + compile
-            ts = []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn())
-                ts.append((time.perf_counter() - t0) * 1e6)
-            rec["us"] = statistics.median(ts)
+            x, held = jnp.asarray(x), hold(s, w)
+            rec["us"] = clock(lambda: apply_jit(s, x, held), reps)
             memo[memo_key] = (rec["us"], s.op)
         rows.append(rec)
     return rows
+
+
+def clock(fn, reps: int) -> float:
+    """Median wall-clock microseconds of ``reps`` calls of ``fn`` after
+    one warmup (trace + compile) call, each ended by
+    ``block_until_ready``."""
+    jax.block_until_ready(fn())
+    ts = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn())
+        ts.append((time.perf_counter() - t0) * 1e6)
+    return statistics.median(ts)

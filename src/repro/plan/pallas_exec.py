@@ -8,15 +8,16 @@ successors directly, and the host sees one completion.  This module is
 that execution model (DESIGN.md Sec. 15):
 
 * :func:`compile_schedule` lowers an entire schedule -- every measured
-  step plus its bp2bs/bs2bp repack -- into ONE jitted program.  Weights
-  are converted/packed once at *compile* time into a device-resident
-  param pytree: BP steps hold int8 limb stacks ``[ceil(bits/7), K, N]``
-  (the form the BP kernel reads), BS-resident steps hold pre-packed
-  ``[bits, K/32, N]`` planes.  Boundary repacks the
-  plan charges stay *in* the program: a ``bp2bs`` step keeps word-form
-  params and packs in-flight (through the fused bitpack-matmul when the
-  schedule fused it), a ``bs2bp`` step keeps plane-form params and
-  unpacks (and splits into limbs) in-flight.
+  step plus its bp2bs/bs2bp repack -- into ONE jitted program, each
+  step the one step function of ``plan.pallas``.  Weights are
+  converted/packed once at *compile* time (``hold``) into a
+  device-resident param pytree: BP steps hold int8 limb stacks
+  ``[ceil(bits/7), K, N]`` (the form the BP kernel reads), BS-resident
+  steps hold pre-packed ``[bits, K/32, N]`` planes.  Boundary repacks
+  the plan charges stay *in* the program (``apply``): a ``bp2bs`` step
+  keeps word-form params and packs in-flight (through the fused
+  bitpack-matmul when the schedule fused it), a ``bs2bp`` step keeps
+  plane-form params and unpacks (and splits into limbs) in-flight.
 * Step results thread to successor activations along the Workload
   ``deps`` DAG (``kernels.ops.thread_activations``) -- real dataflow, so
   XLA cannot elide or reorder the chain, and synthetic operands exist
@@ -40,9 +41,10 @@ that execution model (DESIGN.md Sec. 15):
   step's padded kernel output die with its step, so the device holds no
   more than the result bytes.
 
-Per-step ``run_schedule`` stays authoritative as the differential
-reference: with the same threading it is bit-exact with the chained
-program and with the numpy ``reference_results`` (pinned by
+Per-step ``run_schedule`` runs the same ``apply(s, x, hold(s, w))`` one
+step at a time and stays authoritative as the differential reference:
+with the same threading it is bit-exact with the chained program and
+with the numpy ``reference_results`` (pinned by
 ``tests/test_pallas_exec.py``).
 
 Executables are content-addressed (:class:`ExecutableCache`, the
@@ -61,8 +63,7 @@ from typing import Any, Optional
 import numpy as np
 
 from repro import spans
-from repro.core.cost_model import Layout
-from repro.plan.pallas import MAX_BS_WIDTH, PallasSchedule, synth_inputs
+from repro.plan.pallas import PallasSchedule, apply, hold, synth_inputs
 
 #: results leave the device in flat int32 buffers of at most this many
 #: bytes (a step's result is never split), every copy in flight at once:
@@ -204,11 +205,6 @@ def compile_schedule(schedule: PallasSchedule,
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
-    from repro.kernels.bitpack import bitpack, bitunpack
-    from repro.kernels.bitparallel_matmul import (bitparallel_matmul,
-                                                  split_limbs)
-    from repro.kernels.bitserial_matmul import bitserial_matmul
-    from repro.kernels.fused_bitserial_matmul import fused_bitserial_matmul
 
     with spans.span("schedule.pack"):
         if inputs is None:
@@ -225,30 +221,10 @@ def compile_schedule(schedule: PallasSchedule,
             x, w = inputs[s.op]
             if s.op not in producer:
                 entry[s.op] = jnp.asarray(x)
-            w = jnp.asarray(w)
-            if s.layout is Layout.BP:
-                if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
-                    # the operand arrives plane-resident; the plan-charged
-                    # unpack is part of the program, not of compile
-                    params[s.op] = kops.pack_weights(w, s.width)
-                else:
-                    params[s.op] = kops.bp_limbs(w, s.width)
-            elif s.repack == "bp2bs":
-                # word-resident: the plan-charged pack runs in-program
-                # (folded into the fused kernel when the schedule fused it)
-                params[s.op] = w
-            else:
-                params[s.op] = kops.pack_weights(w, s.width)
+            params[s.op] = hold(s, w)
         # the span covers the placement and conversions, not only their
         # dispatch
         jax.block_until_ready((entry, params))
-
-    def _bs(x, planes):
-        # mirror kops.matmul_bs: bitpack zero-pads K to a multiple of 32
-        k_planes = planes.shape[1] * 32
-        if x.shape[1] != k_planes:
-            x = jnp.pad(x, ((0, 0), (0, k_planes - x.shape[1])))
-        return bitserial_matmul(x, planes)
 
     # each result's place in the flat outputs, in step order
     slots: dict[str, tuple[int, int, int, int]] = {}
@@ -270,17 +246,7 @@ def compile_schedule(schedule: PallasSchedule,
             with jax.named_scope(s.op):
                 x = (kops.thread_activations(out[src], m, k)
                      if src is not None else xs[s.op])
-                w = ps[s.op]
-                if s.layout is Layout.BP:
-                    if s.repack == "bs2bp" and s.width <= MAX_BS_WIDTH:
-                        w = split_limbs(bitunpack(w, k), s.width)
-                    y = bitparallel_matmul(x, w)
-                elif s.kernel == "fused_bitserial_matmul":
-                    y = fused_bitserial_matmul(x, w, s.width)
-                elif s.repack == "bp2bs":
-                    y = _bs(x, bitpack(w, s.width))
-                else:
-                    y = _bs(x, w)
+                y = apply(s, x, ps[s.op])
             out[s.op] = y
             # in place, as soon as the step is done: its padded kernel
             # output is not kept alive to the end of the program

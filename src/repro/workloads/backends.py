@@ -421,144 +421,77 @@ class ExecutorBackend(_SequentialEstimateMany):
 # Pallas (measured wall-clock of the TPU-analogue kernels)
 # ---------------------------------------------------------------------------
 
-#: widest BS weight the bitplane kernels support (uint32 plane words)
-PALLAS_MAX_BS_WIDTH = 32
-#: default per-launch padded-MAC budget (x plane passes for BS):
-#: interpret-mode throughput is ~10^8 MAC/s, so 2^31 bounds one launch
-#: to tens of seconds instead of silently clamping the problem
-PALLAS_MAX_MACS = 2 ** 31
-
-
 class PallasBackend(_SequentialEstimateMany):
     """Measure wall-clock of the grid-tiled Pallas kernels over the
-    *whole op* in both layouts: the BP word kernel vs the BS bitplane
-    kernel at the op's **true** weight precision (one plane pass per
-    bit -- never capped).  Dims are padded only up to each kernel's
-    hardware-minimum tile multiples (``kernels.tiling``); both the true
-    and the padded dims land in the ``OpReport`` so a report can never
-    misstate what was run.  Ops whose padded MAC volume exceeds
-    ``max_macs`` -- or whose width exceeds the kernels' 32-plane limit --
-    report ``supported=False`` with an honest note instead of a clamped
-    or understated number.  Timings are the median of ``reps``
+    *whole op* in both layouts: the BP word kernel vs the fused BS
+    bitpack-matmul at the op's **true** weight precision (one plane pass
+    per bit -- never capped).  Each side is the step the schedule
+    lowering makes of the op (``plan.pallas.step_for``: BP arrival, and
+    a ``bp2bs`` boundary into BS), timed as ``plan.pallas.apply`` on
+    weights ``plan.pallas.hold`` made (``apply_jit``) -- the dims, tiles, width limit
+    and budget the compiled schedule program runs.  Both the true and
+    the padded dims land in the ``OpReport`` so a report can never
+    misstate what was run.  Ops either step cannot run -- no Pallas
+    lowering for the op's kind, a width over the kernels' 32-plane
+    limit, or padded MAC work over ``max_macs`` -- report
+    ``supported=False`` with that step's note instead of a clamped or
+    understated number.  Timings are the median of ``reps``
     post-warmup calls with ``block_until_ready`` (never a single cold
-    wall-clock sample).  ``fused=True`` (default) times the BS side as
-    the one-kernel fused bitpack-matmul; ``fused=False`` times the
-    unfused pack->matmul pipeline, pack pass included."""
+    wall-clock sample)."""
 
     name = "pallas"
 
-    def __init__(self, tile: int = 128, reps: int = 5,
-                 max_macs: int = PALLAS_MAX_MACS, fused: bool = True):
-        self.tile = tile
+    def __init__(self, reps: int = 5, max_macs: Optional[int] = None):
         self.reps = reps
         self.max_macs = max_macs
-        self.fused = fused
 
     def supports(self, workload: Workload) -> bool:
         return any(op.kind in ("matmul", "conv") for op in workload.ops)
 
-    def _dims(self, op: Op) -> tuple[int, int, int]:
-        """True (m, k, n) of the matmul the op lowers to -- un-clamped.
-
-        Conv follows the same lowering ``ExecutorBackend`` prices:
-        ``op.n`` im2col output elements, each a ``op.k``-deep MAC chain,
-        i.e. a GEMV ``(op.n, op.k) @ (op.k, 1)``.
-        """
-        if op.kind == "conv":
-            return op.n, op.k, 1
-        return op.m, op.k, op.n
-
-    def _tilings(self, m: int, k: int, n: int):
-        """(BP tiling, BS tiling) at this backend's block-size hint."""
-        from repro.kernels import tiling as tl
-
-        t = self.tile
-        bp = tl.bp_tiling(m, k, n, block_m=t, block_n=t, block_k=t)
-        bs = (tl.fused_tiling(m, k, n, block_m=t, block_n=t, block_k=t)
-              if self.fused else
-              tl.bs_tiling(m, k, n, block_m=t, block_n=t,
-                           block_k=max(t, 256)))
-        return bp, bs
-
     def estimate(self, workload: Workload,
                  sys: SystemParams = PAPER_SYSTEM) -> Report:
-        import statistics
-        import time
-
         import numpy as np
         import jax
         import jax.numpy as jnp
 
         from repro.kernels import ops as kops
+        from repro.plan.pallas import (DEFAULT_MAX_MACS, apply_jit, clock,
+                                       hold, step_for)
 
         del sys  # wall-clock backend: the host, not the modelled system
+        max_macs = (DEFAULT_MAX_MACS if self.max_macs is None
+                    else self.max_macs)
         rng = np.random.default_rng(0)
         rows = []
         tot_bp = tot_bs = 0.0
         measured = 0
-
-        def clock(fn):
-            """Median of `reps` timed calls after a compile/warmup call;
-            `block_until_ready` keeps async dispatch out of the sample."""
-            jax.block_until_ready(fn())  # warmup / compile
-            samples = []
-            for _ in range(self.reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn())
-                samples.append((time.perf_counter() - t0) * 1e6)
-            return statistics.median(samples)
-
-        bk = dict(block_m=self.tile, block_n=self.tile, block_k=self.tile)
         for op in workload.ops:
-            if op.kind not in ("matmul", "conv"):
-                rows.append(OpReport(op=op.name, kind=op.kind,
-                                     supported=False,
-                                     note="no Pallas kernel for this op"))
-                continue
-            m, k, n = self._dims(op)
-            bits = max(1, op.width)
-            if bits > PALLAS_MAX_BS_WIDTH:
+            bp = step_for(op, Layout.BP, None, max_macs=max_macs)
+            bs = step_for(op, Layout.BS, "bp2bs", fuse_pack=True,
+                          max_macs=max_macs)
+            bad = next((s for s in (bs, bp) if not s.measured), None)
+            if bad is not None:
                 rows.append(OpReport(
                     op=op.name, kind=op.kind, supported=False,
-                    dims=(m, k, n),
-                    note=f"unsupported: width {bits} > "
-                         f"{PALLAS_MAX_BS_WIDTH} plane passes "
-                         "(uint32 plane words) -- not measured"))
+                    dims=bad.dims, padded_dims=bad.padded_dims,
+                    note=bad.note))
                 continue
-            bp_t, bs_t = self._tilings(m, k, n)
-            work = max(bp_t.padded_macs, bs_t.padded_macs * bits)
-            if work > self.max_macs:
-                rows.append(OpReport(
-                    op=op.name, kind=op.kind, supported=False,
-                    dims=(m, k, n), padded_dims=bp_t.padded_dims,
-                    note=f"over budget: {work} padded MACs (BS work = "
-                         f"{bits} planes) > max_macs={self.max_macs} "
-                         "-- not measured"))
-                continue
+            m, k, n = bp.dims
+            bits = op.width
             x = jnp.asarray(rng.integers(-8, 8, (m, k), dtype=np.int32)
                             ).astype(jnp.int8)
-            w = jnp.asarray(rng.integers(0, 1 << min(bits, 31),
-                                         (k, n)).astype(np.int32))
-            limbs = kops.bp_limbs(w, bits)
-            bp_us = clock(lambda: kops.matmul_bp(x, limbs, **bk))
-            if self.fused:
-                bs_us = clock(lambda: kops.matmul_bs_fused(
-                    x, w, bits, **bk))
-                bs_note = "fused"
-            else:
-                # unfused: the pack pass is part of the measured BS path
-                def bs_fn():
-                    return kops.matmul_bs(x, kops.pack_weights(w, bits))
-                bs_us = clock(bs_fn)
-                bs_note = "unfused (pack on path)"
+            w = rng.integers(0, 1 << min(bits, 31), (k, n)).astype(np.int32)
+            bp_w, bs_w = hold(bp, w), hold(bs, w)
+            bp_us = clock(lambda: apply_jit(bp, x, bp_w), self.reps)
+            bs_us = clock(lambda: apply_jit(bs, x, bs_w), self.reps)
             rec = kops.choose_layout(weight_bits=bits, m=m, n=n, k=k)
             rows.append(OpReport(
                 op=op.name, kind=op.kind, bp_us=bp_us, bs_us=bs_us,
-                dims=(m, k, n), padded_dims=bp_t.padded_dims,
+                dims=(m, k, n), padded_dims=bp.padded_dims,
                 note=f"{m}x{k}x{n}@{bits}b "
-                     f"padded_bp={'x'.join(map(str, bp_t.padded_dims))} "
-                     f"padded_bs={'x'.join(map(str, bs_t.padded_dims))} "
-                     f"bs={bs_note}; choose_layout={rec.value}"))
+                     f"padded_bp={'x'.join(map(str, bp.padded_dims))} "
+                     f"padded_bs={'x'.join(map(str, bs.padded_dims))} "
+                     f"bs={bs.kernel}; choose_layout={rec.value}"))
             tot_bp += bp_us
             tot_bs += bs_us
             measured += 1

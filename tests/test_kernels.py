@@ -148,14 +148,14 @@ def test_layout_aware_matmul_dispatch():
     x = jnp.asarray(rng.integers(0, 8, (128, 64), dtype=np.int32)).astype(
         jnp.int8)
     w2 = _rand_words(rng, 64, 128, 2)   # 2-bit, high DoP -> BS
-    y, layout = ops.layout_aware_matmul(x, w2, weight_bits=2)
+    y, layout = ops.planned_matmul(x, w2, weight_bits=2)
     assert layout.value == "BS"
     np.testing.assert_array_equal(
         np.asarray(y), np.asarray(x.astype(jnp.int32) @ w2.astype(jnp.int32)))
 
     w8 = _rand_words(rng, 64, 128, 8)   # 8-bit words -> BP
-    y8, layout8 = ops.layout_aware_matmul(x, w8.astype(jnp.int32) - 0,
-                                          weight_bits=8)
+    y8, layout8 = ops.planned_matmul(x, w8.astype(jnp.int32) - 0,
+                                     weight_bits=8)
     assert layout8.value == "BP"
     # lossless: unsigned 8-bit words no longer wrap through int8 (PR 9)
     np.testing.assert_array_equal(

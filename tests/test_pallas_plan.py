@@ -7,13 +7,16 @@ import pytest
 
 from repro.core.cost_model import Layout
 from repro.plan import (
+    PallasSchedule,
     compile_plan,
+    compile_schedule,
     lower_plan_pallas,
     reference_results,
     run_schedule,
     synth_inputs,
     time_schedule,
 )
+from repro.plan.pallas import apply, hold, step_for
 from repro.workloads.ir import Op, Workload
 
 
@@ -215,3 +218,35 @@ def test_time_schedule_reports_every_step(hybrid_plan):
     for r in rows:
         assert r["us"] is not None and r["us"] > 0
         assert r["dims"] is not None and r["padded_dims"] is not None
+
+
+#: every form a lowered step takes: (layout, repack, fuse_pack, kernel)
+STEP_FORMS = {
+    "bp": (Layout.BP, None, True, "bitparallel_matmul"),
+    "bp_bs2bp": (Layout.BP, "bs2bp", True, "bitparallel_matmul"),
+    "bs": (Layout.BS, None, True, "bitserial_matmul"),
+    "bs_bp2bs_unfused": (Layout.BS, "bp2bs", False, "bitserial_matmul"),
+    "bs_bp2bs_fused": (Layout.BS, "bp2bs", True, "fused_bitserial_matmul"),
+}
+
+
+@pytest.mark.parametrize("form", sorted(STEP_FORMS))
+def test_step_function_matches_reference_and_program(form):
+    """``apply(s, x, hold(s, w))`` -- the step function every path runs
+    -- equals the plain-integer reference and the same step compiled as
+    a one-step schedule program, in each step form (ragged K and N, and
+    a width of two BP limbs)."""
+    import jax.numpy as jnp
+
+    layout, repack, fuse, kernel = STEP_FORMS[form]
+    op = Op(name="mm", kind="matmul", m=3, k=40, n=130, width=12)
+    s = step_for(op, layout, repack, fuse_pack=fuse)
+    assert s.kernel == kernel and s.repack == repack
+    sched = PallasSchedule(workload="one_step", steps=(s,), fuse_pack=fuse)
+    inputs = synth_inputs(sched, seed=21)
+    x, w = inputs["mm"]
+    got = np.asarray(apply(s, jnp.asarray(x), hold(s, w)))
+    np.testing.assert_array_equal(
+        got, reference_results(sched, inputs)["mm"])
+    np.testing.assert_array_equal(
+        got, compile_schedule(sched, inputs, seed=21).run()["mm"])

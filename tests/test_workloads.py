@@ -158,7 +158,7 @@ def test_unknown_workload_and_backend_raise():
 def test_pallas_backend_measures_matmul_tiles():
     from repro.workloads import PallasBackend
 
-    rep = PallasBackend(tile=32).estimate(get_workload("gemv"))
+    rep = PallasBackend().estimate(get_workload("gemv"))
     (row,) = rep.ops
     assert row.supported and row.bp_us > 0 and row.bs_us > 0
     assert rep.summary["measured_ops"] == 1
@@ -172,13 +172,15 @@ def test_pallas_backend_conv_dims_match_executor_lowering():
     """PR-9 regression: conv lowers to the im2col GEMV ExecutorBackend
     prices -- (op.n, op.k, 1) -- not the (op.n, op.k, op.n) square the
     old `m, k, n = op.n, op.k, op.n` bug measured."""
+    from repro.core.cost_model import Layout
+    from repro.plan.pallas import step_for
     from repro.workloads import PallasBackend
 
     be = PallasBackend()
     vgg_convs = [op for op in get_workload("vgg").ops if op.kind == "conv"]
     assert vgg_convs
     for op in vgg_convs:
-        assert be._dims(op) == (op.n, op.k, 1)
+        assert step_for(op, Layout.BP, None).dims == (op.n, op.k, 1)
     # and the full estimate records those dims on every conv row, even
     # ones too large to measure (over budget -> honest modelled row)
     rep = be.estimate(get_workload("vgg13"))
@@ -199,13 +201,13 @@ def test_pallas_backend_runs_true_width_and_rejects_over_32():
 
     w16 = W(name="w16", ops=(
         Op(name="mm", kind="matmul", m=4, k=64, n=64, width=16),))
-    rep = PallasBackend(tile=32, reps=1).estimate(w16)
+    rep = PallasBackend(reps=1).estimate(w16)
     (row,) = rep.ops
     assert row.supported and "@16b" in row.note
 
     w48 = W(name="w48", ops=(
         Op(name="mm", kind="matmul", m=4, k=64, n=64, width=48),))
-    rep = PallasBackend(tile=32, reps=1).estimate(w48)
+    rep = PallasBackend(reps=1).estimate(w48)
     (row,) = rep.ops
     assert not row.supported and "unsupported: width 48" in row.note
     assert row.dims == (4, 64, 64)
